@@ -25,6 +25,7 @@ import numpy as np
 
 from repro.configs import get_config
 from repro.data.pipeline import SyntheticLM
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as M
 from repro.serving import (ContinuousBatchingEngine, GenerationConfig,
                            PagedEngine, ServingEngine)
@@ -120,6 +121,7 @@ def run_paged(cfg, params, traffic, slots, max_prompt, max_new,
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="mixtral-8x7b-lite")
     ap.add_argument("--requests", type=int, default=12)

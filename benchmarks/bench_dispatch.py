@@ -22,6 +22,7 @@ import jax
 import numpy as np
 
 from repro.core import dispatch as D
+from repro.launch.compile_cache import enable_compile_cache
 
 from .common import Row, time_fn
 
@@ -117,6 +118,7 @@ def _tile_skip(plan: D.DispatchPlan, cap: int, f: int = 256,
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="tiny sweep for CI (seconds, not minutes)")

@@ -3,8 +3,8 @@ and ops from the compiled HLO (the TPU analogue of the paper's NCCL
 bandwidth test) for the paper's real-world configs (E2T4 / E4T2 on 8
 devices) and simulated NVL72 (EP9xTP8) / CloudMatrix384 (EP48xTP8).
 
-Runs in subprocesses because each mesh needs its own
---xla_force_host_platform_device_count."""
+Runs in CPU subprocesses because each mesh needs its own
+--xla_force_host_platform_device_count; a failed child fails the run."""
 from __future__ import annotations
 
 import json
@@ -17,10 +17,10 @@ from .common import Row
 _PROG = r"""
 import dataclasses, json, sys
 import jax, jax.numpy as jnp
+from jax.sharding import AxisType
 from repro.configs import get_config
 from repro.core import moe, setp
 from repro.launch.hlo_analysis import analyze_hlo
-from repro.launch.mesh import make_mesh_auto, use_mesh
 from repro.models.layers import split_params
 
 ep, tp, tokens = int(sys.argv[1]), int(sys.argv[2]), int(sys.argv[3])
@@ -33,8 +33,9 @@ params, _ = split_params(moe.make_moe_params(key, cfg))
 x = jax.ShapeDtypeStruct((ep, tokens, cfg.d_model), jnp.float32)
 
 # ETP: EP x TP mesh
-mesh = make_mesh_auto((ep, tp), ("ep", "tp"))
-with use_mesh(mesh):
+mesh = jax.make_mesh((ep, tp), ("ep", "tp"),
+                     axis_types=(AxisType.Auto,) * 2)
+with jax.set_mesh(mesh):
     comp = jax.jit(lambda p, xx: setp.etp_moe_forward(
         p, xx, cfg, mesh, cap_factor=1.5)).lower(params, x).compile()
 etp = analyze_hlo(comp.as_text())
@@ -45,11 +46,12 @@ p_factor = tp
 pp = setp.place_params_strided(
     __import__("repro.core.partition", fromlist=["partial_transform"])
     .partial_transform(params, p_factor), ep * tp)
-mesh2 = make_mesh_auto((1, ep * tp), ("data", "model"))
+mesh2 = jax.make_mesh((1, ep * tp), ("data", "model"),
+                      axis_types=(AxisType.Auto,) * 2)
 from repro.core.policy import TwoTDrop
 pol = TwoTDrop(partition_p=p_factor, t_major=-1.0, t_minor=-1.0)
 x2 = jax.ShapeDtypeStruct((1, ep * tokens, cfg.d_model), jnp.float32)
-with use_mesh(mesh2):
+with jax.set_mesh(mesh2):
     comp2 = jax.jit(lambda p, xx: setp.setp_moe_forward(
         p, xx, cfg, mesh2, policy=pol, cap_factor=1.5,
         cap_multiple=1)).lower(pp, x2).compile()
@@ -76,12 +78,14 @@ def run() -> list[Row]:
         env["XLA_FLAGS"] = (f"--xla_force_host_platform_device_count="
                             f"{ep * tp}")
         env["PYTHONPATH"] = os.path.join(root, "src")
+        # placeholder host devices: the child never touches an accelerator
+        env["JAX_PLATFORMS"] = "cpu"
         p = subprocess.run([sys.executable, "-c", _PROG, str(ep), str(tp),
                             str(tokens)], capture_output=True, text=True,
                            env=env, timeout=900)
         if p.returncode != 0:
-            rows.append((f"fig9/{name}", 0.0, f"ERROR {p.stderr[-200:]}"))
-            continue
+            raise SystemExit(f"fig9/{name}: child exited {p.returncode}: "
+                             f"{p.stderr[-2000:]}")
         res = json.loads(p.stdout.strip().splitlines()[-1])
         ratio = res["etp_total"] / max(res["setp_total"], 1)
         rows.append((

@@ -35,6 +35,7 @@ from repro.configs import get_config
 from repro.core import moe as moe_mod
 from repro.core import policy as policy_mod
 from repro.launch import hlo_analysis
+from repro.launch.compile_cache import enable_compile_cache
 from repro.lint.bench_schema import validate_pipeline_bench
 from repro.lint.hlo_passes import capacity_buffer_count
 from repro.models.layers import split_params
@@ -189,6 +190,7 @@ def run(smoke: bool = False, out_path: str | None = None) -> list[Row]:
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="single tiny shape for CI (seconds)")

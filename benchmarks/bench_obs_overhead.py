@@ -34,6 +34,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.lint.bench_schema import validate_obs_bench
 from repro.models import model as M
 from repro.serving import ContinuousBatchingEngine, GenerationConfig, Request
@@ -169,6 +170,7 @@ def run(smoke: bool = False, out_path: str | None = None) -> dict:
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="tiny workload, no overhead gate (CI check)")

@@ -30,6 +30,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as M
 from repro.serving import (ContinuousBatchingEngine, GenerationConfig,
                            PagedEngine)
@@ -148,6 +149,7 @@ def run(smoke: bool = False, out_path: str | None = None) -> dict:
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="tiny workload (CI end-to-end check)")

@@ -9,6 +9,7 @@ Each run = one hypothesis->change->measure cycle for EXPERIMENTS.md §Perf.
 import os
 os.environ.setdefault("XLA_FLAGS",
                       "--xla_force_host_platform_device_count=512")
+os.environ["JAX_PLATFORMS"] = "cpu"     # placeholder devices, never a chip
 
 import argparse
 import dataclasses

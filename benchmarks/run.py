@@ -10,6 +10,8 @@ import sys
 import time
 import traceback
 
+from repro.launch.compile_cache import enable_compile_cache
+
 from .common import print_rows
 
 BENCHES = [
@@ -28,6 +30,7 @@ BENCHES = [
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None,
                     help="comma-separated bench keys to run")

@@ -190,7 +190,7 @@ def prefer_cumsum_dispatch(n_pairs: int, n_groups: int,
     vectorized pass, while a stable argsort of ~1e4+ keys pays its
     O(N log N) in scalar compares (BENCH_dispatch.json: T=1024..4096/E=8
     runs 0.68-0.86x). Both build bit-identical plans, so the choice is pure
-    performance. TPU/GPU always sort (the dense one-hot is an (N, G)
+    performance. Accelerators always sort (the dense one-hot is an (N, G)
     HBM-traffic bomb there)."""
     if backend is None:
         backend = jax.default_backend()
@@ -205,25 +205,23 @@ def prefer_fused_pipeline(n_tokens: int, n_groups: int, *,
     fused dispatch->FFN->combine Pallas pipeline instead of the
     gather->grouped-FFN->unpermute buffer path?
 
-    On TPU/GPU the streamed kernel is the default at EVERY token count:
-    its VMEM working set is independent of T (pair maps in SMEM, x/out in
-    HBM with double-buffered DMA), it never materializes the
-    (E, capacity, d) buffer, and the bench trajectory
-    (BENCH_moe_pipeline.json) shows it at or above buffer throughput from
-    decode (T=64) through prefill (T=8192). On CPU the kernels run in
-    interpret mode, where the fused kernel still beats the interpreted
-    buffer-path Pallas FFN (same trajectory) but loses to the pure-XLA
-    einsum the non-kernel policies use — so fused follows ``use_kernel``
-    there. All paths agree to fp tolerance; the choice is performance
-    only."""
+    On TPU the streamed kernel is the default at EVERY token count: its
+    VMEM working set is independent of T (pair maps in SMEM, x/out in HBM
+    with double-buffered DMA) and it never materializes the
+    (E, capacity, d) buffer. On CPU the kernels run in interpret mode,
+    where the fused kernel beats the interpreted buffer-path Pallas FFN
+    but loses to the pure-XLA einsum the non-kernel policies use — so fused
+    follows ``use_kernel`` there. Any other backend has no lowering for
+    the TPU kernel and takes the buffer path. All paths agree to fp
+    tolerance; the choice is performance only."""
     if backend is None:
         backend = jax.default_backend()
     del n_tokens, n_groups          # today's rule is shape-independent;
     #                                 the signature keeps per-shape tuning
     #                                 open without call-site churn
-    if backend != "cpu":
+    if backend == "tpu":
         return True
-    return use_kernel
+    return backend == "cpu" and use_kernel
 
 
 def dispatch_plan(group, keep=None, *, n_groups: int, capacity: int,

@@ -294,7 +294,7 @@ def moe_forward_dispatch(params, x, cfg, pairs: Optional[SubExpertPairs] = None,
     x/out in HBM behind double-buffered DMA). ``None`` (the default)
     resolves per shape/backend via
     ``core.dispatch.prefer_fused_pipeline`` — fused everywhere on
-    TPU/GPU, fused iff ``use_kernel`` on CPU interpret. The buffer path
+    TPU, fused iff ``use_kernel`` on CPU interpret. The buffer path
     below stays as its bit-exactness oracle. ``fused_streamed=False``
     selects the whole-array-resident kernel variant (identical math and
     accumulation order — bit-exact vs streamed; bench/debug knob only).

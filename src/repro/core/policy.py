@@ -101,12 +101,14 @@ class SparsityPolicy:
     #                                 read-back). None = auto: resolved per
     #                                 shape/backend at trace time by
     #                                 core.dispatch.prefer_fused_pipeline
-    #                                 (TPU/GPU: always fused; CPU interpret:
+    #                                 (TPU: always fused; CPU interpret:
     #                                 fused iff use_kernel). True/False
     #                                 force the choice.
     capacity_factor: float = 2.0    # dispatch-path expert capacity factor
-    exact_capacity: bool = False    # capacity = T: no overflow drop ever,
-    #                                 so MoE outputs are batch-invariant
+    exact_capacity: bool = False    # worst-case capacities (capacity = T on
+    #                                 the dispatch path): no overflow drop
+    #                                 ever, so MoE outputs are
+    #                                 batch-invariant
     drop_target: Optional[float] = None   # calibrate thresholds in prepare()
 
     _dynamic: Tuple[str, ...] = ()

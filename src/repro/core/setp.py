@@ -32,20 +32,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:  # jax >= 0.6: top-level jax.shard_map (replication check kw: check_vma)
-    from jax import shard_map as _shard_map_impl
-    _REP_CHECK_KW = "check_vma"
-except ImportError:  # older jax: experimental namespace (kw: check_rep)
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-    _REP_CHECK_KW = "check_rep"
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=False):
-    """Version-tolerant shard_map: same call-sites work on old and new JAX."""
-    return _shard_map_impl(f, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs,
-                           **{_REP_CHECK_KW: check_vma})
-
 from . import dispatch as dispatch_mod
 from . import drop as drop_mod
 from . import gating, moe as moe_mod
@@ -91,7 +77,13 @@ def _setp_body(wg, w1, w3, w2, x_loc, *, cfg, n_dev: int, axis: str,
     sub-experts. The ``policy`` decides the keep mask over expanded
     sub-expert pairs; a load-aware policy additionally costs one psum of
     the (D,) pre-drop device histogram. ``thresholds``: optional per-layer
-    calibrated (2,) pair threaded through the shard_map (replicated)."""
+    calibrated (2,) pair threaded through the shard_map (replicated).
+
+    ``policy.exact_capacity`` sizes both seatings for the worst case
+    instead of by capacity factors, so no kept pair can overflow: a token
+    sends at most ``min(K*P, L)`` sub-pairs to one device, and a local
+    sub-expert receives at most one pair per token from each of the
+    ``n_dev`` sources."""
     p_factor = policy.partition_p
     use_kernel = policy.use_kernel
     Bl, Sl, d = x_loc.shape
@@ -151,7 +143,10 @@ def _setp_body(wg, w1, w3, w2, x_loc, *, cfg, n_dev: int, axis: str,
                  "dropped_pairs": dr}
 
     Kp = K * p_factor
-    cap = _ceil_mult(cap_factor * T * Kp / n_dev, cap_multiple)
+    if policy.exact_capacity:
+        cap = _ceil_mult(T * min(Kp, L), cap_multiple)
+    else:
+        cap = _ceil_mult(cap_factor * T * Kp / n_dev, cap_multiple)
 
     # --- dispatch: sort-based seating per destination device ---
     # MAJOR-only flags ride to the owning device (low bit of the id
@@ -177,7 +172,10 @@ def _setp_body(wg, w1, w3, w2, x_loc, *, cfg, n_dev: int, axis: str,
     valid = re2 >= 0
     loc = jnp.where(valid, re2 // 2, 0)
     mfl = valid & ((re2 & 1) == 1)
-    c2 = _ceil_mult(local_cap_factor * n_dev * cap / L, cap_multiple)
+    if policy.exact_capacity:
+        c2 = _ceil_mult(n_dev * T, cap_multiple)
+    else:
+        c2 = _ceil_mult(local_cap_factor * n_dev * cap / L, cap_multiple)
     plan_loc = dispatch_mod.sort_dispatch(loc, valid, n_groups=L,
                                           capacity=c2, major_only=mfl)
     fused = getattr(policy, "fused_pipeline", None)
@@ -269,6 +267,10 @@ def setp_moe_forward(params: Dict, x, cfg, mesh: Mesh, *,
     local-expert-level capacity overflow — the unsanctioned accuracy loss a
     deployment must watch, previously invisible on this path.
 
+    The policy's ``exact_capacity`` hint (``exact_moe`` in the engines)
+    replaces ``cap_factor``/``local_cap_factor`` by worst-case capacities:
+    no overflow, so outputs do not depend on co-batched traffic.
+
     ``return_stats``: instead return ``(y, stats)`` where stats is the
     ``repro.obs`` per-layer dict (kept-pair ``expert_load`` histogram over
     global sub-expert ids plus kept_full/kept_major/dropped_pairs/
@@ -316,7 +318,7 @@ def setp_moe_forward(params: Dict, x, cfg, mesh: Mesh, *,
                     "dropped_pairs": P(), "overflow_pairs": P()}
     else:
         aux_spec = P()
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         fn, mesh=mesh,
         in_specs=tuple(in_specs),
         out_specs=(x_spec, aux_spec), check_vma=False,
@@ -394,7 +396,7 @@ def etp_moe_forward(params: Dict, x, cfg, mesh: Mesh, *,
                              cap_factor=cap_factor,
                              local_cap_factor=local_cap_factor)
     x_spec = P(ep_axis, None, None)
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(), P(ep_axis, None, tp_axis), P(ep_axis, None, tp_axis),
                   P(ep_axis, tp_axis, None), x_spec),

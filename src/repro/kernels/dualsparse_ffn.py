@@ -37,6 +37,16 @@ from jax.experimental.pallas import tpu as pltpu
 from .specs import BlockUse, KernelSpec, dtype_name
 
 
+def _row_view(d: int):
+    """``(R, L)`` slab shape of one d-wide activation row in the streamed
+    kernel: lane-dense ``L = 128`` whenever d allows it. Holding x and out
+    as ``(T, R, L)`` keeps the token axis leading and untiled, so one token
+    row is a whole-tile DMA at any offset instead of a one-row slice of an
+    (8|16, 128)-tiled ``(T, d)`` array, which the TPU compiler refuses."""
+    lanes = 128 if d % 128 == 0 else d
+    return d // lanes, lanes
+
+
 def _resolve_blocks(C: int, f: int, p_factor: int,
                     n_minor_start: int | None, block_c: int, block_f: int):
     """Shared geometry: clamp blocks to the logical dims, pad to block
@@ -71,9 +81,9 @@ def grouped_swiglu_kernel_spec(E: int, C: int, d: int, f: int, *,
     dt = dtype_name(dtype)
     blocks = (
         BlockUse("counts_full", (E,), "int32", "in", streamed=False,
-                 control=True),
+                 control=True, space="smem"),
         BlockUse("counts_major", (E,), "int32", "in", streamed=False,
-                 control=True),
+                 control=True, space="smem"),
         BlockUse("x", (1, g["block_c"], d), dt, "in"),
         BlockUse("w1", (1, d, g["block_f"]), dt, "in"),
         BlockUse("w3", (1, d, g["block_f"]), dt, "in"),
@@ -96,7 +106,8 @@ def fused_moe_pipeline_kernel_spec(T: int, d: int, f: int, E: int,
     """Static launch description of ``fused_moe_pipeline_pallas``.
 
     ``streamed=True`` (production): the per-pair maps ride in SMEM via
-    scalar prefetch, x and the f32 output live in ANY (HBM) memory, and
+    scalar prefetch, x and the f32 output live in ANY (HBM) memory as
+    ``(T, R, L)`` token-row slabs (``_row_view``), and
     VMEM holds only the revolving weight tiles plus the double-buffered
     (block_c, d) gather tiles and two f32 staging tiles — the working set
     is independent of T, so the 16 MB budget holds at prefill scale.
@@ -123,17 +134,20 @@ def fused_moe_pipeline_kernel_spec(T: int, d: int, f: int, E: int,
                  streamed=False, control=True, space=map_space),
     ]
     if streamed:
+        row = _row_view(d)
         blocks += [
-            BlockUse("x", (T, d), dt, "in", streamed=False,
+            BlockUse("x", (T,) + row, dt, "in", streamed=False,
                      space="any", dma_buffers=2),
             BlockUse("w1", (1, d, g["block_f"]), dt, "in"),
             BlockUse("w3", (1, d, g["block_f"]), dt, "in"),
             BlockUse("w2", (1, g["block_f"], d), dt, "in"),
-            BlockUse("out", (T, d), "float32", "out", streamed=False,
+            BlockUse("out", (T,) + row, "float32", "out", streamed=False,
                      space="any", dma_buffers=1),
-            BlockUse("x_tiles", (2 * g["block_c"], d), dt, "scratch"),
-            BlockUse("acc_scratch", (g["block_c"], d), "float32", "scratch"),
-            BlockUse("out_stage", (g["block_c"], d), "float32", "scratch"),
+            BlockUse("x_tiles", (2 * g["block_c"],) + row, dt, "scratch"),
+            BlockUse("acc_scratch", (g["block_c"],) + row, "float32",
+                     "scratch"),
+            BlockUse("out_stage", (g["block_c"],) + row, "float32",
+                     "scratch"),
         ]
     else:
         blocks += [
@@ -152,7 +166,7 @@ def fused_moe_pipeline_kernel_spec(T: int, d: int, f: int, E: int,
     return KernelSpec("fused_moe_pipeline", grid, tuple(blocks), meta)
 
 
-def _kernel(counts_full_ref, counts_major_ref,   # tiny (E,) control arrays
+def _kernel(counts_full_ref, counts_major_ref,   # (E,) control, SMEM
             x_ref, w1_ref, w3_ref, w2_ref, out_ref, *,
             block_c: int, block_f: int, n_minor_start: int):
     e = pl.program_id(0)
@@ -216,8 +230,8 @@ def grouped_swiglu_pallas(x, w1, w3, w2, counts_full=None, counts_major=None,
     S-ETP local buffers, where each group IS a single sub-expert and
     ``counts_major`` only tracks the row-mode ordering).
 
-    ``interpret=True`` executes the kernel body in Python on CPU (this
-    container); on TPU pass interpret=False.
+    ``interpret=True`` executes the kernel body in Python on CPU; on TPU
+    pass interpret=False.
     """
     E, C, d = x.shape
     Es, _, f = w1.shape
@@ -250,24 +264,31 @@ def grouped_swiglu_pallas(x, w1, w3, w2, counts_full=None, counts_major=None,
         _kernel, block_c=block_c, block_f=block_f,
         n_minor_start=n_minor_start)
 
-    out = pl.pallas_call(
-        kernel,
+    # the (E,) counts ride in SMEM via scalar prefetch: the kernel reads
+    # them at a dynamic expert index, which a VMEM vector cannot serve
+    def x_map(e, c, f, *_refs):
+        return (e, c, 0)
+
+    def w13_map(e, c, f, *_refs):
+        return (e * p_factor + f // nf_sub, 0, f % nf_sub)
+
+    def w2_map(e, c, f, *_refs):
+        return (e * p_factor + f // nf_sub, f % nf_sub, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((E,), lambda e, c, f: (0,)),          # counts_full
-            pl.BlockSpec((E,), lambda e, c, f: (0,)),          # counts_major
-            pl.BlockSpec((1, block_c, d), lambda e, c, f: (e, c, 0)),
-            pl.BlockSpec((1, d, block_f),
-                         lambda e, c, f: (e * p_factor + f // nf_sub, 0,
-                                          f % nf_sub)),
-            pl.BlockSpec((1, d, block_f),
-                         lambda e, c, f: (e * p_factor + f // nf_sub, 0,
-                                          f % nf_sub)),
-            pl.BlockSpec((1, block_f, d),
-                         lambda e, c, f: (e * p_factor + f // nf_sub,
-                                          f % nf_sub, 0)),
+            pl.BlockSpec((1, block_c, d), x_map),
+            pl.BlockSpec((1, d, block_f), w13_map),
+            pl.BlockSpec((1, d, block_f), w13_map),
+            pl.BlockSpec((1, block_f, d), w2_map),
         ],
-        out_specs=pl.BlockSpec((1, block_c, d), lambda e, c, f: (e, c, 0)),
+        out_specs=pl.BlockSpec((1, block_c, d), x_map),
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((E, Cp, d), jnp.float32),
         interpret=interpret,
     )(counts_full.astype(jnp.int32), counts_major.astype(jnp.int32),
@@ -354,13 +375,18 @@ def _fused_pipeline_streamed_kernel(
         offs_ref, cf_ref, cm_ref, tok_ref, wc_ref,   # scalar prefetch (SMEM)
         x_hbm, w1_ref, w3_ref, w2_ref, out_hbm,      # ANY + revolving VMEM
         x_tiles, acc_scr, stage, gather_sem, rw_sem, *,
-        T: int, block_c: int, block_f: int, n_minor_start: int,
+        T: int, d: int, block_c: int, block_f: int, n_minor_start: int,
         n_f: int, n_c: int, n_blocks: int, E: int):
     """Streamed variant: VMEM holds only the revolving weight tiles plus
     ``x_tiles`` (2 x (block_c, d) — double-buffered gather destination),
     ``acc_scr`` and one f32 staging tile. The pair maps arrive through
     scalar prefetch (SMEM), x and out stay in ANY (HBM) memory and every
-    touch is an explicit ``make_async_copy``:
+    touch is an explicit ``make_async_copy``.
+
+    Every activation row is held as a ``(R, L)`` slab (``_row_view``): the
+    token axis is a leading, untiled dimension, so a single token row is a
+    whole-tile DMA at any offset — the TPU's (8|16, 128) tiling never sees
+    a one-row slice.
 
       * gather — the row block of the NEXT (e, c) pair is DMA'd from
         x into the other half of ``x_tiles`` while the current block
@@ -392,10 +418,10 @@ def _fused_pipeline_streamed_kernel(
     start = offs_ref[e] + row0
 
     def gather_dma(row, dst_slot, j):
-        # one (1, d) row: x[tok] -> x_tiles[dst_slot*block_c + j]
+        # one token row: x[tok] -> x_tiles[dst_slot*block_c + j]
         return pltpu.make_async_copy(
-            x_hbm.at[pl.ds(row, 1), :],
-            x_tiles.at[pl.ds(dst_slot * block_c + j, 1), :],
+            x_hbm.at[pl.ds(row, 1)],
+            x_tiles.at[pl.ds(dst_slot * block_c + j, 1)],
             gather_sem.at[dst_slot])
 
     def start_block_gather(blk, dst_slot):
@@ -423,8 +449,7 @@ def _fused_pipeline_streamed_kernel(
         if T >= block_c:                 # static: loop body traces eagerly
             def zbody(k, _):
                 cp = pltpu.make_async_copy(
-                    stage.at[:, :],
-                    out_hbm.at[pl.ds(k * block_c, block_c), :], rw_sem)
+                    stage, out_hbm.at[pl.ds(k * block_c, block_c)], rw_sem)
                 cp.start()
                 cp.wait()
                 return 0
@@ -432,8 +457,8 @@ def _fused_pipeline_streamed_kernel(
         tail = T % block_c
         if tail:
             cp = pltpu.make_async_copy(
-                stage.at[pl.ds(0, tail), :],
-                out_hbm.at[pl.ds(T - tail, tail), :], rw_sem)
+                stage.at[pl.ds(0, tail)],
+                out_hbm.at[pl.ds(T - tail, tail)], rw_sem)
             cp.start()
             cp.wait()
 
@@ -460,7 +485,7 @@ def _fused_pipeline_streamed_kernel(
 
     @pl.when(live)
     def _compute():
-        x = x_tiles[pl.ds(slot * block_c, block_c), :]   # (block_c, d)
+        x = x_tiles[pl.ds(slot * block_c, block_c)].reshape(block_c, d)
         w1 = w1_ref[0]                                   # (d, block_f)
         w3 = w3_ref[0]
         w2 = w2_ref[0]                                   # (block_f, d)
@@ -471,7 +496,8 @@ def _fused_pipeline_streamed_kernel(
         valid_rows = jnp.where(nids < n_minor_start, cf + cm, cf)  # (1, bf)
         h = jnp.where(rows < valid_rows, h, 0.0)
         acc_scr[...] += jnp.dot(h.astype(w2.dtype), w2,
-                                preferred_element_type=jnp.float32)
+                                preferred_element_type=jnp.float32
+                                ).reshape(acc_scr.shape)
 
     @pl.when((f == n_f - 1) & any_rows)
     def _scatter():
@@ -481,14 +507,13 @@ def _fused_pipeline_streamed_kernel(
         def body(j, _):
             tok = tok_ref[start + j]
             w = jnp.where(row0 + j < cf + cm, wc_ref[start + j], 0.0)
-            rd = pltpu.make_async_copy(out_hbm.at[pl.ds(tok, 1), :],
-                                       stage.at[pl.ds(0, 1), :], rw_sem)
+            rd = pltpu.make_async_copy(out_hbm.at[pl.ds(tok, 1)],
+                                       stage.at[pl.ds(0, 1)], rw_sem)
             rd.start()
             rd.wait()
-            stage[pl.ds(0, 1), :] = (stage[pl.ds(0, 1), :] +
-                                     w * acc_scr[pl.ds(j, 1), :])
-            wr = pltpu.make_async_copy(stage.at[pl.ds(0, 1), :],
-                                       out_hbm.at[pl.ds(tok, 1), :], rw_sem)
+            stage[0] = stage[0] + w * acc_scr[j]
+            wr = pltpu.make_async_copy(stage.at[pl.ds(0, 1)],
+                                       out_hbm.at[pl.ds(tok, 1)], rw_sem)
             wr.start()
             wr.wait()
             return 0
@@ -533,8 +558,8 @@ def fused_moe_pipeline_pallas(x, w1, w3, w2, group_offsets, counts_full,
     ``pltpu.make_async_copy`` — the VMEM working set is independent of T.
     ``streamed=False`` keeps the original whole-array-resident layout
     (the streamed kernel's bit-exactness oracle and the lint negative
-    test). Both produce identical bits; ``interpret=True`` (this
-    container) validates the block/skip/DMA logic on CPU.
+    test; interpret mode only). Both produce identical bits;
+    ``interpret=True`` validates the block/skip/DMA logic on CPU.
     """
     T, d = x.shape
     Es, _, f = w1.shape
@@ -554,6 +579,10 @@ def fused_moe_pipeline_pallas(x, w1, w3, w2, group_offsets, counts_full,
     pf, nf_sub, n_f = g["pad_f"], g["nf_sub"], g["n_f"]
     n_minor_start = g["n_minor_start"]
     grid = spec.grid
+    if not streamed and not interpret:
+        raise NotImplementedError(
+            "the resident fused kernel (streamed=False) is an interpret-mode "
+            "oracle only; its one-row VMEM slices do not lower on TPU")
     if pf:
         w1 = jnp.pad(w1, ((0, 0), (0, 0), (0, pf)))
         w3 = jnp.pad(w3, ((0, 0), (0, 0), (0, pf)))
@@ -567,8 +596,9 @@ def fused_moe_pipeline_pallas(x, w1, w3, w2, group_offsets, counts_full,
 
     if streamed:
         n_c = grid[1]
+        R, L = _row_view(d)
         kernel = functools.partial(
-            _fused_pipeline_streamed_kernel, T=T, block_c=block_c,
+            _fused_pipeline_streamed_kernel, T=T, d=d, block_c=block_c,
             block_f=block_f, n_minor_start=n_minor_start, n_f=n_f,
             n_c=n_c, n_blocks=E * n_c, E=E)
 
@@ -583,16 +613,16 @@ def fused_moe_pipeline_pallas(x, w1, w3, w2, group_offsets, counts_full,
             num_scalar_prefetch=5,
             grid=grid,
             in_specs=[
-                pl.BlockSpec(memory_space=pltpu.ANY),        # x (HBM)
+                pl.BlockSpec(memory_space=pl.ANY),           # x (HBM)
                 pl.BlockSpec((1, d, block_f), w13_map),
                 pl.BlockSpec((1, d, block_f), w13_map),
                 pl.BlockSpec((1, block_f, d), w2_map),
             ],
-            out_specs=pl.BlockSpec(memory_space=pltpu.ANY),  # out (HBM)
+            out_specs=pl.BlockSpec(memory_space=pl.ANY),     # out (HBM)
             scratch_shapes=[
-                pltpu.VMEM((2 * block_c, d), x.dtype),       # gather tiles
-                pltpu.VMEM((block_c, d), jnp.float32),       # output accum
-                pltpu.VMEM((block_c, d), jnp.float32),       # zero/RMW stage
+                pltpu.VMEM((2 * block_c, R, L), x.dtype),    # gather tiles
+                pltpu.VMEM((block_c, R, L), jnp.float32),    # output accum
+                pltpu.VMEM((block_c, R, L), jnp.float32),    # zero/RMW stage
                 pltpu.SemaphoreType.DMA((2,)),               # per-slot gather
                 pltpu.SemaphoreType.DMA,                     # zero + RMW
             ],
@@ -600,10 +630,10 @@ def fused_moe_pipeline_pallas(x, w1, w3, w2, group_offsets, counts_full,
         out = pl.pallas_call(
             kernel,
             grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((T, d), jnp.float32),
+            out_shape=jax.ShapeDtypeStruct((T, R, L), jnp.float32),
             interpret=interpret,
-        )(*operands)
-        return out.astype(x.dtype)
+        )(*operands[:5], x.reshape(T, R, L), *operands[6:])
+        return out.reshape(T, d).astype(x.dtype)
 
     kernel = functools.partial(
         _fused_pipeline_kernel, block_c=block_c, block_f=block_f,

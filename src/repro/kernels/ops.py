@@ -1,8 +1,8 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-On this CPU container the kernels run in ``interpret=True`` (the kernel body
-executes in Python, validating block logic exactly); on a real TPU backend
-they lower natively.
+The kernels lower natively on a TPU backend and run in ``interpret=True``
+(the kernel body executes in Python, validating block logic exactly) on the
+CPU backend. Any other backend is refused rather than silently interpreted.
 """
 from __future__ import annotations
 
@@ -21,8 +21,16 @@ __all__ = ["fused_moe_pipeline", "grouped_swiglu", "grouped_swiglu_ref",
            "fused_moe_pipeline_pallas", "grouped_swiglu_pallas"]
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def _interpret() -> bool:
+    """True on CPU (interpreter), False on TPU (native lowering); raises on
+    any other backend — these are TPU kernels, and interpreting them on an
+    accelerator would hide the device behind a Python loop."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(f"Pallas TPU kernels cannot run on backend {backend!r}")
 
 
 @functools.partial(jax.jit, static_argnames=("capacity", "p_factor",
@@ -43,14 +51,15 @@ def fused_moe_pipeline(x, w1, w3, w2, group_offsets, counts_full,
     ``streamed=True`` (default): pair maps in scalar-prefetch SMEM, x/out
     in ANY (HBM) memory with explicit double-buffered DMA, so the VMEM
     working set is independent of T (prefill-safe). ``streamed=False``
-    keeps the whole-array-resident PR-6 layout (bit-identical output).
+    keeps the whole-array-resident PR-6 layout (bit-identical output,
+    CPU interpreter only).
     See kernels.dualsparse_ffn.fused_moe_pipeline_pallas for the
     contract; ``core.dispatch.sorted_pair_arrays`` builds the pair maps."""
     return fused_moe_pipeline_pallas(
         x, w1, w3, w2, group_offsets, counts_full, counts_major,
         tok_sorted, combine_sorted, capacity=capacity, p_factor=p_factor,
         n_minor_start=n_minor_start, block_c=block_c, block_f=block_f,
-        streamed=streamed, interpret=not _on_tpu())
+        streamed=streamed, interpret=_interpret())
 
 
 @functools.partial(jax.jit, static_argnames=("p_factor", "n_minor_start",
@@ -68,7 +77,7 @@ def grouped_swiglu(x, w1, w3, w2, counts_full=None, counts_major=None,
     return grouped_swiglu_pallas(
         x, w1, w3, w2, counts_full, counts_major,
         p_factor=p_factor, n_minor_start=n_minor_start,
-        block_c=block_c, block_f=block_f, interpret=not _on_tpu())
+        block_c=block_c, block_f=block_f, interpret=_interpret())
 
 
 grouped_swiglu_ref = ref.grouped_swiglu_ref

@@ -1,8 +1,9 @@
 # The dry-run (and ONLY the dry-run) builds the production mesh out of 512
-# placeholder host devices. These two lines MUST run before any other import
-# (jax locks the device count on first init).
+# placeholder host CPU devices. These lines MUST run before any other import
+# (jax locks the platform and device count on first init).
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 import argparse
 import json
@@ -156,7 +157,7 @@ def run_one(arch: str, shape_name: str, multi_pod: bool,
         jitted = jax.jit(step, in_shardings=shardings,
                          donate_argnums=tuple(range(len(args))) if donate
                          and shape.kind != "prefill" else ())
-        with mesh_mod.use_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = jitted.lower(*args)
             compiled = lowered.compile()
         rec["compile_s"] = round(time.time() - t0, 1)
@@ -267,7 +268,7 @@ def _run_many(combos, out: Optional[str], jobs: int):
                "--arch", a, "--shape", s] + (["--multi-pod"] if m else [])
         if out:
             cmd += ["--out", out]
-        env = dict(os.environ)
+        env = dict(os.environ, JAX_PLATFORMS="cpu")
         return combo, subprocess.Popen(cmd, stdout=subprocess.PIPE,
                                        stderr=subprocess.DEVNULL, env=env)
 

@@ -6,34 +6,23 @@ from __future__ import annotations
 import jax
 
 
-def make_mesh_auto(shape, axes):
-    """Version-tolerant jax.make_mesh: newer JAX wants explicit Auto axis
-    types; older JAX has no AxisType and every axis is implicitly auto."""
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
-
-
-def use_mesh(mesh):
-    """Version-tolerant ambient-mesh context manager: ``jax.set_mesh`` on
-    newer JAX; on older JAX the Mesh object itself is the context manager."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
+def _auto_mesh(shape, axes):
+    # GSPMD-style meshes: sharding follows constraints, not array types
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_auto(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(model: int = 4):
     """Small mesh over whatever host devices exist (tests/benchmarks)."""
     n = len(jax.devices())
     model = min(model, n)
-    return make_mesh_auto((n // model, model), ("data", "model"))
+    return _auto_mesh((n // model, model), ("data", "model"))
 
 
 # TPU v5e-class hardware constants used by the roofline analysis.

@@ -20,11 +20,13 @@ import argparse
 import time
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config, list_archs
 from repro.core.policy import POLICIES, make_policy
 from repro.data.pipeline import SyntheticLM, calibration_activations
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as M
 from repro.serving import (ContinuousBatchingEngine, GenerationConfig,
                            PagedEngine, ServingEngine)
@@ -67,7 +69,7 @@ def main():
                          "read-back). Default is AUTO: the per-shape "
                          "heuristic (core.dispatch.prefer_fused_pipeline) "
                          "picks fused wherever the bench shows a win — "
-                         "always on TPU/GPU, with use_kernel on CPU")
+                         "always on TPU, with use_kernel on CPU")
     ap.add_argument("--no-fused-pipeline", dest="fused_pipeline",
                     action="store_false",
                     help="force the buffer path (disable the fused kernel "
@@ -94,11 +96,12 @@ def main():
                          "into this directory (TensorBoard/XProf format)")
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
     key = jax.random.PRNGKey(args.seed)
-    params = M.init_params(key, cfg)
+    params = M.init_params(key, cfg, dtype=jnp.bfloat16)
 
     policy_name = args.policy
     if policy_name is None and args.dualsparse:

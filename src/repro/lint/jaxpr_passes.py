@@ -14,7 +14,7 @@ from typing import Iterator, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import core as jcore
+from jax.extend import core as jcore
 
 from .findings import Finding, Severity
 
@@ -25,8 +25,10 @@ from .findings import Finding, Severity
 _BAD_DTYPES = ("float64", "complex128")
 
 # primitives that force a host round-trip / side channel inside a step
-_HOST_PRIMS = ("pure_callback", "io_callback", "debug_callback", "callback",
-               "infeed", "outfeed")
+_HOST_PRIMS = ("pure_callback", "io_callback", "debug_callback",
+               "debug_print", "callback", "infeed", "outfeed")
+# debug output: a WARNING (it stalls the stream, but changes no result)
+_DEBUG_PRIMS = ("debug_callback", "debug_print")
 
 
 def _subjaxprs(params) -> Iterator[jcore.Jaxpr]:
@@ -63,7 +65,7 @@ def _aval_dtype_name(aval) -> Optional[str]:
 def check_dtype_promotion(jaxpr, entry: str) -> List[Finding]:
     """Flag f64/c128 result avals and explicit converts into them.
 
-    Run the traced function under ``jax.experimental.enable_x64`` when
+    Run the traced function under ``jax.enable_x64(True)`` when
     probing for *latent* promotions: code that is f32-explicit stays clean,
     code that leans on weak-type defaults lights up."""
     out: List[Finding] = []
@@ -108,7 +110,7 @@ def check_host_sync(jaxpr, entry: str) -> List[Finding]:
             counts[eqn.primitive.name] = counts.get(eqn.primitive.name,
                                                     0) + 1
     for prim, n in sorted(counts.items()):
-        sev = Severity.WARNING if prim == "debug_callback" else Severity.ERROR
+        sev = Severity.WARNING if prim in _DEBUG_PRIMS else Severity.ERROR
         out.append(Finding(
             "jaxpr-hostsync", prim, sev, entry,
             f"{n}x '{prim}' inside the traced entry point",

@@ -319,7 +319,7 @@ def _calib_entries(cfg) -> List[LintEntry]:
 
     def trace_threshold():
         scores = _sds((256, cfg.top_k))
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             def fn(scores):
                 t = drop_mod.calibrate_threshold(scores, 0.25)
                 rates = drop_mod.threshold_to_drop_rate(
@@ -332,7 +332,7 @@ def _calib_entries(cfg) -> List[LintEntry]:
     def trace_load_aware():
         hist = _sds((cfg.n_experts,), jnp.int32)
         idx = _sds((64, cfg.top_k), jnp.int32)
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             def fn(hist, idx):
                 loads = load_aware.device_loads(hist, 2)
                 t_dev = load_aware.step_down_thresholds(loads, 0.12)

@@ -28,12 +28,18 @@ def _mod(cfg: ModelConfig):
 # ---------------------------------------------------------------------------
 
 def init_params_and_axes(rng, cfg: ModelConfig, dtype=jnp.float32):
-    """Returns (params, axes) trees. dtype applied to all floating leaves."""
-    tree = _mod(cfg).make_model_params(rng, cfg)
-    params, axes = L.split_params(tree)
-    if dtype != jnp.float32:
-        params = jax.tree.map(lambda a: a.astype(dtype), params)
-    return params, axes
+    """Returns (params, axes) trees. dtype applied to all floating leaves.
+
+    The tree is drawn under one ``jit`` with the cast inside, so XLA fuses
+    each leaf's draw into its cast: no float32 copy of a bf16 tree, and no
+    per-layer trees beside their stacked copy. Peak device memory is the
+    returned tree itself."""
+    def build(key):
+        params, _ = L.split_params(_mod(cfg).make_model_params(key, cfg))
+        return jax.tree.map(lambda a: a.astype(dtype), params)
+
+    _, axes = abstract_params_and_axes(cfg)
+    return jax.jit(build)(rng), axes
 
 
 def init_params(rng, cfg: ModelConfig, dtype=jnp.float32):

@@ -40,6 +40,7 @@ from typing import Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..configs.base import ModelConfig
 from ..core.policy import NoDrop, SparsityPolicy
@@ -82,6 +83,18 @@ def exact_moe_dist(dist: Optional[DistContext]) -> DistContext:
     from ..launch.mesh import make_host_mesh
     return DistContext(mesh=make_host_mesh(1), moe_impl="dispatch",
                        policy=NoDrop(exact_capacity=True))
+
+
+def place_like_steps(tree, dist: Optional[DistContext]):
+    """Commit an eagerly built engine cache to the placement its jitted
+    steps return. With a DistContext the model's sharding constraints give
+    every step output a NamedSharding on ``dist.mesh``, while a cache built
+    outside jit is single-device; JAX keys its trace cache on that
+    difference, so the first donated cache would trace (and, on a chip,
+    compile) every step twice."""
+    if dist is None:
+        return tree
+    return jax.device_put(tree, NamedSharding(dist.mesh, P()))
 
 
 class ServingEngine(EngineBase):
@@ -339,12 +352,6 @@ class ContinuousBatchingEngine(EngineBase):
         self.max_new_tokens = max_new_tokens
         if exact_moe and cfg.is_moe:
             dist = exact_moe_dist(dist)
-            if dist.moe_impl == "setp":
-                import warnings
-                warnings.warn(
-                    "exact_moe only governs the dispatch MoE path; the setp "
-                    "(shard_map EP) path uses its own capacity factors, so "
-                    "outputs may depend on co-batched traffic", stacklevel=2)
         self.dist = dist
         self.context_len = M.context_len_for(cfg, max_prompt_len,
                                              max_new_tokens)
@@ -429,9 +436,9 @@ class ContinuousBatchingEngine(EngineBase):
         self._prefill_insert = jax.jit(prefill_insert, donate_argnums=(4,))
         self._decode = jax.jit(decode, donate_argnums=(2,))
         spec = metrics_spec(cfg, params) if metrics else None
-        self._cache = M.init_cache(cfg, n_slots, self.context_len,
-                                   per_slot_pos=True, dtype=cache_dtype,
-                                   metrics_spec=spec)
+        self._cache = place_like_steps(
+            M.init_cache(cfg, n_slots, self.context_len, per_slot_pos=True,
+                         dtype=cache_dtype, metrics_spec=spec), dist)
         self._slots: List[Optional[_SlotState]] = [None] * n_slots
         self._last = np.full((n_slots, 1), pad_token, np.int32)
         self._active = np.zeros((n_slots,), bool)
@@ -607,9 +614,9 @@ class ContinuousBatchingEngine(EngineBase):
     @property
     def overflow_pairs(self) -> int:
         """Total token-expert pairs silently dropped by capacity overflow
-        since engine construction (0 under ``exact_moe`` on the dispatch
-        path; a setp-backed engine now also counts its psum'd device-level
-        and local-expert overflow, which exact_moe does NOT pin). The
+        since engine construction (0 under ``exact_moe`` on either MoE
+        path; a setp-backed engine counts its psum'd device-level and
+        local-expert overflow). The
         counter rides in the decode cache, so reading it costs one scalar
         transfer — no per-step sync."""
         m = self._device_metrics()

@@ -49,7 +49,7 @@ from ..models import transformer
 from ..models.transformer import DistContext
 from ..obs import MetricsSnapshot, metrics_spec
 from .api import EngineBase, GenerationConfig, Request
-from .engine import exact_moe_dist, merge_policy_override
+from .engine import exact_moe_dist, merge_policy_override, place_like_steps
 
 
 class PageAllocator:
@@ -190,9 +190,10 @@ class PagedEngine(EngineBase):
         self._alloc = PageAllocator(n_pages)
         self._layout = attn.PagedLayout(page_size)
         self._page_table = np.zeros((n_slots, self.pages_per_slot), np.int32)
-        self._cache = transformer.init_paged_cache(
+        self._cache = place_like_steps(transformer.init_paged_cache(
             cfg, n_pages, page_size, n_slots, dtype=cache_dtype,
-            metrics_spec=metrics_spec(cfg, params) if metrics else None)
+            metrics_spec=metrics_spec(cfg, params) if metrics else None),
+            dist)
         self._slots: List[Optional[_SlotState]] = [None] * n_slots
         self._last = np.full((n_slots, 1), pad_token, np.int32)
         self._active = np.zeros((n_slots,), bool)
@@ -466,6 +467,15 @@ class PagedEngine(EngineBase):
             self._last[slot, 0] = tok
             self._emit(slot, tok)
         return True
+
+    def decode_hlo(self) -> str:
+        """Optimized HLO text of the jitted decode step at the engine's
+        current shapes — for auditing what the step runs on the device
+        (e.g. that the MoE kernel lowered natively)."""
+        return self._decode.lower(
+            self.params, jnp.asarray(self._last), self._cache,
+            jnp.asarray(self._active), jnp.asarray(self._page_table),
+            self._stacked_policy()).compile().as_text()
 
     # -- stats -----------------------------------------------------------
 

@@ -6,8 +6,16 @@ import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
+import hypothesis
 import jax
 import pytest
+
+# every property test draws 20 examples with no deadline (interpret-mode
+# Pallas kernels and first-call jit compiles are slow per example)
+hypothesis.settings.register_profile(
+    "ci", deadline=None, max_examples=20,
+    suppress_health_check=list(hypothesis.HealthCheck))
+hypothesis.settings.load_profile("ci")
 
 
 @pytest.fixture(scope="session")

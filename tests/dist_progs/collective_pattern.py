@@ -4,12 +4,12 @@ import json
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import AxisType
 
 from repro.configs import get_config
 from repro.core import moe, setp
 from repro.launch.hlo_analysis import analyze_hlo
 from repro.models.layers import split_params
-from repro.launch.mesh import make_mesh_auto, use_mesh
 
 
 def main():
@@ -19,15 +19,17 @@ def main():
     B, S, d = 8, 32, cfg.d_model
     x = jax.ShapeDtypeStruct((B, S, d), jnp.float32)
 
-    mesh = make_mesh_auto((2, 4), ("data", "model"))
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     pl = setp.place_params_strided(params, 4)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         comp = jax.jit(lambda p, xx: setp.setp_moe_forward(
             p, xx, cfg, mesh, cap_factor=2.0)).lower(pl, x).compile()
     c1 = analyze_hlo(comp.as_text())
 
-    mesh2 = make_mesh_auto((4, 2), ("ep", "tp"))
-    with use_mesh(mesh2):
+    mesh2 = jax.make_mesh((4, 2), ("ep", "tp"),
+                          axis_types=(AxisType.Auto,) * 2)
+    with jax.set_mesh(mesh2):
         comp2 = jax.jit(lambda p, xx: setp.etp_moe_forward(
             p, xx, cfg, mesh2, cap_factor=2.0)).lower(params, x).compile()
     c2 = analyze_hlo(comp2.as_text())

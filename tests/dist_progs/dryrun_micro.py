@@ -5,6 +5,7 @@ import json
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import AxisType
 
 from repro.configs import get_config
 from repro.launch import specs
@@ -13,12 +14,12 @@ from repro.models import model as M
 from repro.models.transformer import DistContext
 from repro.optim import adamw
 from repro.optim.adamw import AdamWState
-from repro.launch.mesh import make_mesh_auto, use_mesh
 
 
 def main():
     cfg = get_config("qwen3-moe-30b-a3b").reduced()
-    mesh = make_mesh_auto((2, 4), ("data", "model"))
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     params, axes = M.abstract_params_and_axes(cfg, jnp.float32)
     psh = specs.param_shardings(cfg, params, axes, mesh)
     opt = adamw(1e-4)
@@ -29,7 +30,7 @@ def main():
     bsh = specs.batch_shardings(cfg, batch, mesh)
     dist = DistContext(mesh=mesh, moe_impl="setp")
     step = M.make_train_step(cfg, opt, dist=dist)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         comp = jax.jit(step, in_shardings=(psh, osh, bsh)).lower(
             params, ost, batch).compile()
     c = analyze_hlo(comp.as_text())

@@ -13,11 +13,11 @@ import json
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import AxisType
 
 from repro.configs import get_config
 from repro.core import dispatch, gating, moe, reconstruct, setp
 from repro.core.policy import LoadAwareTwoT
-from repro.launch.mesh import make_mesh_auto, use_mesh
 from repro.models.layers import split_params
 
 RECORDED = []
@@ -28,7 +28,8 @@ def main():
     key = jax.random.PRNGKey(0)
     params, _ = split_params(moe.make_moe_params(key, cfg))
     params["wg"] = params["wg"] * 20.0          # spread the gating scores
-    mesh = make_mesh_auto((2, 4), ("data", "model"))
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     n_dev, d = 4, cfg.d_model
     toks = jax.random.normal(jax.random.PRNGKey(1), (8, d)) * 0.5
 
@@ -57,7 +58,7 @@ def main():
 
     def run(x):
         RECORDED.clear()
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             y = setp.setp_moe_forward(pr, x, cfg, mesh, policy=la,
                                       cap_factor=4.0, local_cap_factor=8.0,
                                       wire_dtype=jnp.float32)

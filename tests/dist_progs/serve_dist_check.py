@@ -5,20 +5,21 @@ import json
 
 import jax
 import numpy as np
+from jax.sharding import AxisType
 
 from repro.configs import get_config
 from repro.data.pipeline import SyntheticLM, calibration_activations
 from repro.models import model as M
 from repro.models.transformer import DistContext
 from repro.serving import GenerationConfig, ServingEngine
-from repro.launch.mesh import make_mesh_auto, use_mesh
 
 
 def main():
     cfg = get_config("olmoe-lite")
     key = jax.random.PRNGKey(0)
     params = M.init_params(key, cfg)
-    mesh = make_mesh_auto((2, 4), ("data", "model"))
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     calib = calibration_activations(jax.random.fold_in(key, 7), 256,
                                     cfg.d_model)
     from repro.core.policy import make_policy
@@ -29,7 +30,7 @@ def main():
     prompts = [np.asarray(src.sample_batch(jax.random.fold_in(key, i), 1,
                                            12)["tokens"][0])
                for i in range(2)]
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         eng = ServingEngine(cfg, tparams, batch_size=2, max_prompt_len=12,
                             max_new_tokens=4, dist=dist)
         res = eng.generate(prompts, GenerationConfig(max_new_tokens=4))

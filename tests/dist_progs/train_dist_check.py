@@ -4,6 +4,7 @@ import json
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import AxisType
 
 from repro.configs import get_config
 from repro.data import pipeline
@@ -11,12 +12,12 @@ from repro.launch import specs
 from repro.models import model as M
 from repro.models.transformer import DistContext
 from repro.optim import adamw
-from repro.launch.mesh import make_mesh_auto, use_mesh
 
 
 def main():
     cfg = get_config("qwen3-moe-30b-a3b").reduced()
-    mesh = make_mesh_auto((2, 4), ("data", "model"))
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     key = jax.random.PRNGKey(0)
     params, axes = M.init_params_and_axes(key, cfg)
     psh = specs.param_shardings(cfg, params, axes, mesh)
@@ -27,7 +28,7 @@ def main():
     step = jax.jit(M.make_train_step(cfg, opt, dist=dist))
     loader = pipeline.make_loader(cfg, 8, 32)
     losses = []
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         for i in range(6):
             params, ost, loss = step(params, ost, loader.get_batch(i))
             losses.append(float(loss))

@@ -8,18 +8,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-
-try:
-    import hypothesis
-    import hypothesis.strategies as st
-    from hypothesis import given
-
-    hypothesis.settings.register_profile(
-        "ci", deadline=None, max_examples=20,
-        suppress_health_check=list(hypothesis.HealthCheck))
-    hypothesis.settings.load_profile("ci")
-except ImportError:
-    from _hypothesis_compat import st, given, settings  # noqa: F401
+import hypothesis.strategies as st
+from hypothesis import given
 
 from repro.core import dispatch as D
 from repro.core import drop, gating, moe, setp

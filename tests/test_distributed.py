@@ -29,6 +29,11 @@ def test_setp_exactness():
     assert res["dualsparse_keepall_err"] < 1e-5
     assert res["etp_err"] < 1e-5
     assert res["load_aware_finite"]
+    # exact_capacity: the skewed router overflows the default seating, and
+    # the worst-case seating is exact
+    assert res["default_overflow"] > 0
+    assert res["exact_overflow"] == 0
+    assert res["exact_err"] < 1e-5
 
 
 def test_setp_uses_only_all_to_all():

@@ -146,7 +146,7 @@ def test_calibration_dtypes_pinned_under_x64(rng):
     silently promote) every calibration output stays float32. The lint's
     calib/threshold entry traces the same guarantee statically."""
     scores = jax.random.uniform(rng, (16, 8), dtype=jnp.float32)
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         t = drop.calibrate_threshold(scores, 0.3)
         rates = drop.threshold_to_drop_rate(scores, [0.05, 0.1, 0.2])
         per_layer = drop.calibrate_per_layer_thresholds([scores, scores],

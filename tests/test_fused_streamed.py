@@ -7,9 +7,7 @@ variant it replaced, so the two must agree BIT-FOR-BIT on every layout;
 both match the buffer path to tolerance only (per-token K-sum order
 differs). Property sweep covers ragged ``T % block_c != 0``, empty
 experts, P in {1, 2}, and capacity-overflow pressure — plus a pinned
-representative grid naming each edge. Uses real hypothesis when installed
-and the deterministic ``_hypothesis_compat`` sweep otherwise (this
-container ships without it).
+representative grid naming each edge (hypothesis, 20 examples).
 """
 import dataclasses
 
@@ -17,22 +15,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from hypothesis import given, strategies as st
 
 from repro.core import dispatch as D
 from repro.core import gating, moe
 from repro.core.policy import TwoTDrop, make_policy
 from repro.kernels import ops as kops
-
-try:
-    import hypothesis
-    from hypothesis import given, strategies as st
-
-    hypothesis.settings.register_profile(
-        "ci", deadline=None, max_examples=20,
-        suppress_health_check=list(hypothesis.HealthCheck))
-    hypothesis.settings.load_profile("ci")
-except ImportError:
-    from _hypothesis_compat import st, given  # noqa: F401
 
 
 def _check_case(seed: int, T: int, E: int, P: int, K: int, block_c: int,
@@ -155,11 +143,12 @@ def test_streamed_equals_resident_production_layout(moe_cfg, moe_params,
 # ---------------------------------------------------------------------------
 
 def test_prefer_fused_pipeline_table():
-    """Non-CPU backends: always fused (the streamed kernel's VMEM working
-    set is T-independent). CPU interpret: fused iff the buffer path would
-    also run interpreted kernels (BENCH_moe_pipeline.json trajectory)."""
+    """TPU: always fused (the streamed kernel's VMEM working set is
+    T-independent). CPU interpret: fused iff the buffer path would also
+    run interpreted kernels (BENCH_moe_pipeline.json trajectory). Any other
+    backend cannot lower the TPU kernel and takes the buffer path."""
     assert D.prefer_fused_pipeline(8192, 64, backend="tpu")
-    assert D.prefer_fused_pipeline(1, 4, backend="gpu")
+    assert not D.prefer_fused_pipeline(1, 4, backend="gpu")
     assert D.prefer_fused_pipeline(8192, 4, use_kernel=True, backend="cpu")
     assert not D.prefer_fused_pipeline(8192, 4, use_kernel=False,
                                        backend="cpu")

@@ -29,7 +29,7 @@ def test_dtype_pass_catches_injected_f64():
     def bad(x):
         return jnp.cumsum(x.astype(jnp.float64))   # seeded upcast
 
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         jaxpr = jax.make_jaxpr(bad)(
             jax.ShapeDtypeStruct((8,), jnp.float32))
     found = jaxpr_passes.check_dtype_promotion(jaxpr, "seeded")
@@ -45,7 +45,7 @@ def test_dtype_pass_catches_weak_type_promotion():
         hist = jnp.arange(scores.shape[0])
         return hist / hist.size                    # i64/int -> f64 on x64
 
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         jaxpr = jax.make_jaxpr(leaky)(
             jax.ShapeDtypeStruct((32,), jnp.float32))
     found = jaxpr_passes.check_dtype_promotion(jaxpr, "seeded")

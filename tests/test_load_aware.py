@@ -75,7 +75,7 @@ def test_load_aware_dtypes_pinned_under_x64():
     """Regression for the f32-explicit histogram math: an int histogram
     divided/averaged without the explicit casts would promote to f64 under
     jax_enable_x64 (the lint's calib/load_aware entry checks the trace)."""
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         hist = jnp.arange(8, dtype=jnp.int32)
         loads = load_aware.device_loads(hist, 2)
         ts = load_aware.step_down_thresholds(loads, 0.12)

@@ -1,24 +1,11 @@
-"""Hypothesis property-based tests on the system's invariants.
-
-Falls back to the deterministic randomized sweep in ``_hypothesis_compat``
-when hypothesis is not installed (the CI container does not ship it)."""
+"""Hypothesis property-based tests on the system's invariants."""
 import dataclasses
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-try:
-    import hypothesis
-    import hypothesis.strategies as st
-    from hypothesis import given
-
-    hypothesis.settings.register_profile(
-        "ci", deadline=None, max_examples=20,
-        suppress_health_check=list(hypothesis.HealthCheck))
-    hypothesis.settings.load_profile("ci")
-except ImportError:
-    from _hypothesis_compat import st, given, settings  # noqa: F401
+import hypothesis.strategies as st
+from hypothesis import given
 
 from repro.core import drop, gating, load_aware, moe, partition
 from repro.models.layers import split_params
