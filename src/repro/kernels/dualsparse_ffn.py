@@ -632,6 +632,7 @@ def fused_moe_pipeline_pallas(x, w1, w3, w2, group_offsets, counts_full,
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((T, R, L), jnp.float32),
             interpret=interpret,
+            name="fused_moe_pipeline",
         )(*operands[:5], x.reshape(T, R, L), *operands[6:])
         return out.reshape(T, d).astype(x.dtype)
 
@@ -666,5 +667,6 @@ def fused_moe_pipeline_pallas(x, w1, w3, w2, group_offsets, counts_full,
             pltpu.VMEM((block_c, d), jnp.float32),           # output accum
         ],
         interpret=interpret,
+        name="fused_moe_pipeline",
     )(*operands)
     return out.astype(x.dtype)

@@ -136,23 +136,24 @@ def main():
         for i in range(args.requests)]
 
     metrics = not args.no_metrics
+    trace = bool(args.trace_out)
     if args.engine == "continuous":
         eng = ContinuousBatchingEngine(
             cfg, params, n_slots=args.slots or args.batch_size,
             max_prompt_len=args.prompt_len, max_new_tokens=args.new_tokens,
-            dist=dist, metrics=metrics)
+            dist=dist, metrics=metrics, trace=trace)
     elif args.engine == "paged":
         eng = PagedEngine(
             cfg, params, n_slots=args.slots or args.batch_size,
             page_size=args.page_size, chunk_size=args.chunk_size,
             max_prompt_len=args.prompt_len, max_new_tokens=args.new_tokens,
             dist=dist, prefix_cache=not args.no_prefix_cache,
-            metrics=metrics)
+            metrics=metrics, trace=trace)
     else:
         eng = ServingEngine(cfg, params, batch_size=args.batch_size,
                             max_prompt_len=args.prompt_len,
                             max_new_tokens=args.new_tokens, dist=dist,
-                            metrics=metrics)
+                            metrics=metrics, trace=trace)
 
     server = None
     if args.metrics_port is not None:

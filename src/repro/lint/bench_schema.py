@@ -49,12 +49,6 @@ SERVING_SWEEP_ROW = {"shared_prefix_frac": _NUM, "hit_rate": _NUM,
                      "throughput_tok_s": _NUM, "chunk_steps": int,
                      "prefill_tokens": int}
 
-OBS_TOP = {"bench": str, "unit": str, "note": str, "runs": list}
-OBS_RUN = {"timestamp": str, "host": dict, "smoke": bool, "rows": list}
-OBS_ROW = {"engine": str, "decode_steps": int,
-           "decode_us_on": _NUM, "decode_us_off": _NUM,
-           "tok_s_on": _NUM, "tok_s_off": _NUM, "overhead_frac": _NUM}
-
 
 def _check_keys(obj: Dict, schema: Dict, where: str,
                 optional: Dict = None) -> List[str]:
@@ -125,34 +119,10 @@ def validate_serving_bench(doc: Dict) -> List[str]:
     return errs
 
 
-def validate_obs_bench(doc: Dict) -> List[str]:
-    """Errors in a BENCH_obs_overhead.json document (append-only runs of
-    metrics-on vs metrics-off decode throughput). ``overhead_frac`` is the
-    relative decode-time cost of the traced metrics seam and must be a
-    sane fraction (the bench itself gates the <= 5%% budget)."""
-    errs = _check_keys(doc, OBS_TOP, "top-level")
-    for i, run in enumerate(doc.get("runs", []) or []):
-        errs += _check_keys(run, OBS_RUN, f"runs[{i}]")
-        if not isinstance(run, dict):
-            continue
-        if isinstance(run.get("host"), dict):
-            errs += _check_keys(run["host"], HOST, f"runs[{i}].host")
-        for j, row in enumerate(run.get("rows", []) or []):
-            errs += _check_keys(row, OBS_ROW, f"runs[{i}].rows[{j}]")
-            if isinstance(row, dict) \
-                    and isinstance(row.get("overhead_frac"), _NUM) \
-                    and not -1.0 <= row["overhead_frac"] <= 10.0:
-                errs.append(f"runs[{i}].rows[{j}]: overhead_frac "
-                            f"{row['overhead_frac']} is not a credible "
-                            "on/off ratio")
-    return errs
-
-
 _VALIDATORS = {
     "BENCH_dispatch.json": validate_dispatch_bench,
     "BENCH_moe_pipeline.json": validate_pipeline_bench,
     "BENCH_serving_offline.json": validate_serving_bench,
-    "BENCH_obs_overhead.json": validate_obs_bench,
 }
 
 

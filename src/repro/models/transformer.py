@@ -183,7 +183,8 @@ def _moe_forward(p, x, cfg, dist: Optional[DistContext], aux: bool = False,
     # per-request/per-slot threshold leaves come in shaped (B,): expand them
     # to per-token so routing broadcasts over the flattened (B*S, d) block
     policy = policy.per_token(B, S)
-    pairs = policy.route(p, xt, cfg)
+    with jax.named_scope("route"):
+        pairs = policy.route(p, xt, cfg)
     # exact capacity: one expert receives at most one pair per token, so
     # capacity == T guarantees zero overflow drops at any load skew
     y, overflow = moe_mod.moe_forward_dispatch(
@@ -204,6 +205,21 @@ def _moe_forward(p, x, cfg, dist: Optional[DistContext], aux: bool = False,
     return y.reshape(B, S, d), aux_val, overflow
 
 
+def _ffn_block(bp, x, cfg, dist, collect_stats):
+    """ln2 + MoE (or dense MLP) + residual, under the ``moe`` (``mlp``)
+    named scope. Returns (x, moe_overflow or obs stats dict)."""
+    overflow = jnp.zeros((), jnp.int32)
+    with jax.named_scope("moe" if "moe" in bp else "mlp"):
+        h = L.rms_norm(x, bp["ln2"], cfg.norm_eps)
+        if "moe" in bp:
+            y, _, overflow = _moe_forward(bp["moe"], h, cfg, dist,
+                                          collect=collect_stats)
+            x = x + y
+        else:
+            x = x + L.apply_mlp(bp["mlp"], h, cfg.mlp_kind)
+    return x, overflow
+
+
 def block_forward(bp, x, positions, cfg, *, window: int = 0,
                   dist: Optional[DistContext] = None, capture_cap: int = 0,
                   cache_dtype=jnp.bfloat16, with_aux: bool = False,
@@ -221,29 +237,24 @@ def block_forward(bp, x, positions, cfg, *, window: int = 0,
             return x + y, st, no_overflow
         x = x + mm.mamba2_forward(bp["mamba"], h, cfg)
         return (x, jnp.zeros(())) if with_aux else x
-    h = L.rms_norm(x, bp["ln1"], cfg.norm_eps)
     cache_layer = None
-    if capture_cap:
-        y, cache_layer = _attn_forward(bp["attn"], h, positions, cfg,
-                                       window=window, dist=dist,
-                                       capture_cap=capture_cap,
-                                       cache_dtype=cache_dtype)
-        x = x + y
-    else:
-        x = x + _attn_forward(bp["attn"], h, positions, cfg, window=window,
-                              dist=dist)
-    h = L.rms_norm(x, bp["ln2"], cfg.norm_eps)
-    overflow = no_overflow
-    if "moe" in bp:
-        if with_aux:
-            y, aux, _ = _moe_forward(bp["moe"], h, cfg, dist, aux=True)
+    with jax.named_scope("attention"):
+        h = L.rms_norm(x, bp["ln1"], cfg.norm_eps)
+        if capture_cap:
+            y, cache_layer = _attn_forward(bp["attn"], h, positions, cfg,
+                                           window=window, dist=dist,
+                                           capture_cap=capture_cap,
+                                           cache_dtype=cache_dtype)
             x = x + y
-            return x, aux
-        y, _, overflow = _moe_forward(bp["moe"], h, cfg, dist,
-                                      collect=collect_stats)
-        x = x + y
-    else:
-        x = x + L.apply_mlp(bp["mlp"], h, cfg.mlp_kind)
+        else:
+            x = x + _attn_forward(bp["attn"], h, positions, cfg,
+                                  window=window, dist=dist)
+    if with_aux and "moe" in bp:
+        with jax.named_scope("moe"):
+            h = L.rms_norm(x, bp["ln2"], cfg.norm_eps)
+            y, aux, _ = _moe_forward(bp["moe"], h, cfg, dist, aux=True)
+        return x + y, aux
+    x, overflow = _ffn_block(bp, x, cfg, dist, collect_stats)
     if with_aux:
         return x, jnp.zeros(())
     return (x, cache_layer, overflow) if capture_cap else x
@@ -264,24 +275,18 @@ def block_decode(bp, x, cache_layer, pos, cfg, *, window: int = 0,
         st = mm.MambaState(cache_layer["conv"], cache_layer["ssm"])
         y, st = mm.mamba2_decode(bp["mamba"], h, st, cfg)
         return x + y, {"conv": st.conv, "ssm": st.ssm}, no_overflow
-    h = L.rms_norm(x, bp["ln1"], cfg.norm_eps)
-    if cfg.attn_kind == "mla":
-        y, cache_layer = attn.mla_decode_attention(
-            bp["attn"], h, cache_layer, pos, cfg, window)
-    else:
-        y, cache_layer = attn.gqa_decode_attention(
-            bp["attn"], h, cache_layer, pos, cfg, window,
-            layout=layout, page_table=page_table, write_mask=write_mask,
-            read_len=read_len)
-    x = x + y
-    h = L.rms_norm(x, bp["ln2"], cfg.norm_eps)
-    overflow = no_overflow
-    if "moe" in bp:
-        y, _, overflow = _moe_forward(bp["moe"], h, cfg, dist,
-                                      collect=collect_stats)
+    with jax.named_scope("attention"):
+        h = L.rms_norm(x, bp["ln1"], cfg.norm_eps)
+        if cfg.attn_kind == "mla":
+            y, cache_layer = attn.mla_decode_attention(
+                bp["attn"], h, cache_layer, pos, cfg, window)
+        else:
+            y, cache_layer = attn.gqa_decode_attention(
+                bp["attn"], h, cache_layer, pos, cfg, window,
+                layout=layout, page_table=page_table, write_mask=write_mask,
+                read_len=read_len)
         x = x + y
-    else:
-        x = x + L.apply_mlp(bp["mlp"], h, cfg.mlp_kind)
+    x, overflow = _ffn_block(bp, x, cfg, dist, collect_stats)
     return x, cache_layer, overflow
 
 
@@ -585,13 +590,15 @@ def decode_step(params, token, cache, cfg, *, window: int = 0,
     suppresses KV writes for inactive slots (their pos still advances; the
     engine owns per-slot positions)."""
     pos = cache["pos"]
-    x = L.embed(params["embed"], token)
+    with jax.named_scope("embed"):
+        x = L.embed(params["embed"], token)
     x, new_cache = stack_decode(params, x, cache, pos, cfg, window=window,
                                 dist=dist, layout=layout,
                                 page_table=page_table, write_mask=write_mask,
                                 read_len=read_len)
-    x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = L.unembed(params["embed"], x)
+    with jax.named_scope("lm_head"):
+        x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
+        logits = L.unembed(params["embed"], x)
     new_cache["pos"] = pos + 1
     return logits, new_cache
 
@@ -607,19 +614,13 @@ def chunk_block(bp, x, cache_layer, slot, start, valid_len, cfg, *,
     """One block over a (1,C,d) prompt chunk of a single slot, appending its
     K/V into the decode cache. Returns (x, cache_layer, moe_overflow) —
     obs stats dict in the third slot under ``collect_stats``."""
-    h = L.rms_norm(x, bp["ln1"], cfg.norm_eps)
-    y, cache_layer = attn.gqa_chunk_attention(
-        bp["attn"], h, cache_layer, slot, start, valid_len, cfg,
-        layout=layout, page_table=page_table, read_len=read_len)
-    x = x + y
-    h = L.rms_norm(x, bp["ln2"], cfg.norm_eps)
-    overflow = jnp.zeros((), jnp.int32)
-    if "moe" in bp:
-        y, _, overflow = _moe_forward(bp["moe"], h, cfg, dist,
-                                      collect=collect_stats)
+    with jax.named_scope("attention"):
+        h = L.rms_norm(x, bp["ln1"], cfg.norm_eps)
+        y, cache_layer = attn.gqa_chunk_attention(
+            bp["attn"], h, cache_layer, slot, start, valid_len, cfg,
+            layout=layout, page_table=page_table, read_len=read_len)
         x = x + y
-    else:
-        x = x + L.apply_mlp(bp["mlp"], h, cfg.mlp_kind)
+    x, overflow = _ffn_block(bp, x, cfg, dist, collect_stats)
     return x, cache_layer, overflow
 
 
@@ -643,7 +644,8 @@ def chunk_step(params, tokens, slot, start, valid_len, cache, cfg, *,
     slot = jnp.asarray(slot, jnp.int32)
     start = jnp.asarray(start, jnp.int32)
     valid_len = jnp.asarray(valid_len, jnp.int32)
-    x = L.embed(params["embed"], tokens)
+    with jax.named_scope("embed"):
+        x = L.embed(params["embed"], tokens)
 
     collect = "metrics" in cache  # static structural gate, as stack_decode
 
@@ -657,8 +659,9 @@ def chunk_step(params, tokens, slot, start, valid_len, cache, cfg, *,
 
     x, (new_layers, ofs) = jax.lax.scan(
         body, x, (params["blocks"], cache["layers"]))
-    x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = L.unembed(params["embed"], x)
+    with jax.named_scope("lm_head"):
+        x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
+        logits = L.unembed(params["embed"], x)
     new_cache = ObsCache({"layers": new_layers,
                           "pos": cache["pos"].at[slot].set(start + valid_len)})
     if collect:

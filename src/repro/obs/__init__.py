@@ -5,8 +5,8 @@ Three layers (ISSUE 9 / ROADMAP item 5 sensor substrate):
 * ``obs.metrics`` — ``MetricsState``, a pytree of int32 counters and
   per-layer expert-load histograms that rides INSIDE the jitted decode
   cache (zero host syncs, traced leaves so value churn never retraces).
-* ``obs.tracing`` — ``SpanTracer``, a host-side wall-clock span recorder
-  (submit/prefill_chunk/decode/retire) exportable as Chrome-trace JSON.
+* ``obs.tracing`` — ``SpanTracer``: engine spans as ``engine_*`` profiler
+  annotations, with an opt-in buffer exportable as Chrome-trace JSON.
 * ``obs.export`` — ``MetricsSnapshot`` + Prometheus text exposition,
   structured JSON log lines, and a scrape server for the serve CLI.
 """

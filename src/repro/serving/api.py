@@ -61,11 +61,15 @@ class Request:
 class Result:
     uid: int
     tokens: List[int]
-    prefill_s: float = 0.0
     decode_s: float = 0.0
-    submitted_s: float = 0.0          # arrival time (engine clock)
+    # lifecycle stamps, seconds on the engine clock: submitted (or its
+    # arrival time) <= admitted (slot and KV granted) <= prefill_start
+    # (first prefill dispatched) <= first_token; None until reached
+    submitted_s: float = 0.0
+    admitted_s: Optional[float] = None
+    prefill_start_s: Optional[float] = None
+    first_token_s: Optional[float] = None
     finished_s: float = 0.0           # completion time (engine clock)
-    first_token_s: Optional[float] = None   # first token emission time
 
     @property
     def latency_s(self) -> float:
@@ -130,7 +134,7 @@ class EngineBase:
         (or None); ``_metrics_hook(snap)`` — add engine-specific series.
     """
 
-    def __init__(self, *, metrics: bool = True):
+    def __init__(self, *, metrics: bool = True, trace: bool = False):
         self._queue: Deque[Tuple[int, Request]] = collections.deque()
         self._results: Dict[int, Result] = {}
         self._undrained: List[int] = []
@@ -138,7 +142,9 @@ class EngineBase:
         self._clock_origin: Optional[float] = None
         self._flush = False
         self.metrics_enabled = metrics
-        self.tracer = SpanTracer(enabled=metrics)
+        # engine spans are always profiler annotations; ``trace`` also
+        # keeps them in the tracer's Chrome-trace buffer
+        self.tracer = SpanTracer(enabled=trace)
         # compile vs steady step timing (see generate_timed / step())
         self._compile_s = 0.0
         self._steady_s = 0.0

@@ -105,8 +105,8 @@ class ServingEngine(EngineBase):
                  window: int = 0, pad_token: int = 0,
                  dist: Optional[DistContext] = None,
                  exact_moe: bool = False, cache_dtype=jnp.bfloat16,
-                 metrics: bool = True):
-        super().__init__(metrics=metrics)
+                 metrics: bool = True, trace: bool = False):
+        super().__init__(metrics=metrics, trace=trace)
         self.cfg = cfg
         self.params = params
         self.batch_size = batch_size
@@ -226,12 +226,11 @@ class ServingEngine(EngineBase):
         B = len(batch)
         b = self._make_batch([r.prompt for _, r in batch])
         policy = self._policy_for(gens[0])
-        t0 = time.perf_counter()
+        for u in uids:
+            res = self._results[u]
+            res.admitted_s = res.prefill_start_s = self._now()
         with self.tracer.span("prefill", batch=B):
-            with jax.profiler.TraceAnnotation("engine_prefill"):
-                logits, cache = self._prefill(self.params, b, policy)
-            logits.block_until_ready()
-        t_prefill = time.perf_counter() - t0
+            logits, cache = self._prefill(self.params, b, policy)
         last = jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32)
         done = np.zeros(B, bool)
         max_steps = max(g.max_new_tokens for g in gens)
@@ -249,7 +248,7 @@ class ServingEngine(EngineBase):
                         done[i] = True
                 if done.all():
                     break
-                with jax.profiler.TraceAnnotation("engine_decode"):
+                with self.tracer.span("decode", batch=B):
                     logits, cache = self._serve(self.params, last, cache,
                                                 policy)
                 last = self._next_tokens(logits, gens, uids, step)
@@ -262,7 +261,6 @@ class ServingEngine(EngineBase):
                 else self._dev_metrics + m
         now = self._now()
         for u in uids:
-            self._results[u].prefill_s = t_prefill
             self._results[u].decode_s = t_decode
             self._results[u].finished_s = now
             self.tracer.instant("retire", uid=u)
@@ -334,7 +332,7 @@ class ContinuousBatchingEngine(EngineBase):
                  max_prompt_len: int = 512, max_new_tokens: int = 128,
                  pad_token: int = 0, dist: Optional[DistContext] = None,
                  exact_moe: bool = True, cache_dtype=jnp.bfloat16,
-                 metrics: bool = True):
+                 metrics: bool = True, trace: bool = False):
         if cfg.family in ("audio", "ssm", "hybrid"):
             # ssm/hybrid: the Mamba recurrence runs over trailing pad tokens
             # during right-padded prefill and pollutes the captured decode
@@ -343,7 +341,7 @@ class ContinuousBatchingEngine(EngineBase):
             raise NotImplementedError(
                 f"continuous batching supports attention-based decoder-only "
                 f"families, not {cfg.family!r}")
-        super().__init__(metrics=metrics)
+        super().__init__(metrics=metrics, trace=trace)
         self.cfg = cfg
         self.params = params
         self.n_slots = n_slots
@@ -522,17 +520,15 @@ class ContinuousBatchingEngine(EngineBase):
                 self._slot_pol[:, slot] = leaves
                 req_policy = jax.tree_util.tree_unflatten(
                     self._policy_treedef, [jnp.asarray(l) for l in leaves])
-            t0 = time.perf_counter()
+            res = self._results[uid]
+            res.admitted_s = res.prefill_start_s = self._now()
             with self.tracer.span("prefill_insert", uid=uid, slot=slot,
-                                  prompt_len=len(req.prompt)), \
-                    jax.profiler.TraceAnnotation("engine_prefill_insert"):
+                                  prompt_len=len(req.prompt)):
                 first, self._cache = self._prefill_insert(
                     self.params, jnp.asarray(toks),
                     jnp.asarray(len(req.prompt), jnp.int32),
                     jnp.asarray(slot, jnp.int32), self._cache, req_policy)
                 first = int(first)
-            res = self._results[uid]
-            res.prefill_s = time.perf_counter() - t0
             self._slots[slot] = _SlotState(uid=uid, gen=req.gen)
             self._active[slot] = True
             self._last[slot, 0] = first
@@ -560,8 +556,7 @@ class ContinuousBatchingEngine(EngineBase):
         self._admit()
         if not self._active.any():
             return bool(self._queue)
-        with self.tracer.span("decode", batch=int(self._active.sum())), \
-                jax.profiler.TraceAnnotation("engine_decode"):
+        with self.tracer.span("decode", batch=int(self._active.sum())):
             logits, greedy, self._cache = self._decode(
                 self.params, jnp.asarray(self._last), self._cache,
                 jnp.asarray(self._active), self._stacked_policy())

@@ -36,7 +36,6 @@ from __future__ import annotations
 
 import dataclasses
 import hashlib
-import time
 from typing import Dict, List, Optional, Tuple
 
 import jax
@@ -159,14 +158,14 @@ class PagedEngine(EngineBase):
                  n_pages: Optional[int] = None, pad_token: int = 0,
                  dist: Optional[DistContext] = None, exact_moe: bool = True,
                  cache_dtype=jnp.bfloat16, prefix_cache: bool = True,
-                 metrics: bool = True):
+                 metrics: bool = True, trace: bool = False):
         if (cfg.family in ("audio", "ssm", "hybrid")
                 or cfg.attn_kind == "mla" or cfg.frontend):
             raise NotImplementedError(
                 "paged serving supports GQA attention decoder-only text "
                 "models (chunked prefill has no recurrent-state or "
                 "frontend-token analog yet)")
-        super().__init__(metrics=metrics)
+        super().__init__(metrics=metrics, trace=trace)
         self.cfg = cfg
         self.params = params
         self.n_slots = n_slots
@@ -227,9 +226,11 @@ class PagedEngine(EngineBase):
             logits, new = transformer.chunk_step(
                 params, tokens, slot, start, valid_len, cache, cfg,
                 layout=layout, page_table=page_table, read_len=mpl, dist=d)
-            last = jax.lax.dynamic_index_in_dim(logits[0], valid_len - 1,
-                                                axis=0, keepdims=False)
-            return jnp.argmax(last).astype(jnp.int32), new
+            with jax.named_scope("lm_head"):
+                last = jax.lax.dynamic_index_in_dim(
+                    logits[0], valid_len - 1, axis=0, keepdims=False)
+                first = jnp.argmax(last).astype(jnp.int32)
+            return first, new
 
         def decode(params, tokens, cache, active, page_table, policy):
             self.decode_traces += 1
@@ -239,7 +240,8 @@ class PagedEngine(EngineBase):
                 params, tokens, cache, cfg, dist=d, layout=layout,
                 page_table=page_table, write_mask=active, read_len=ctx)
             new["pos"] = jnp.where(active, new["pos"], cache["pos"])
-            greedy = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
+            with jax.named_scope("lm_head"):
+                greedy = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
             return logits[:, -1], greedy, new
 
         self._chunk_insert = jax.jit(chunk_insert, donate_argnums=(5,))
@@ -358,6 +360,7 @@ class PagedEngine(EngineBase):
                 uid=uid, gen=req.gen, prompt=req.prompt, n_pages=len(pages),
                 next_start=start)
             self._cache["pos"] = self._cache["pos"].at[slot].set(start)
+            self._results[uid].admitted_s = self._now()
             admitted += 1
             self.n_admitted += 1
         return admitted
@@ -403,79 +406,101 @@ class PagedEngine(EngineBase):
         valid = min(self.chunk_size, plen - start)
         toks = np.full((1, self.chunk_size), self.pad_token, np.int32)
         toks[0, :valid] = st.prompt[start:start + valid]
-        t0 = time.perf_counter()
+        res = self._results[st.uid]
+        if res.prefill_start_s is None:
+            res.prefill_start_s = self._now()
         with self.tracer.span("prefill_chunk", uid=st.uid, slot=slot,
-                              start=start, n_tokens=valid), \
-                jax.profiler.TraceAnnotation("engine_prefill_chunk"):
+                              start=start, n_tokens=valid):
             first, self._cache = self._chunk_insert(
                 self.params, jnp.asarray(toks), jnp.asarray(slot, jnp.int32),
                 jnp.asarray(start, jnp.int32), jnp.asarray(valid, jnp.int32),
                 self._cache, jnp.asarray(self._page_table),
                 self._slot_policy(st.gen))
-        self._results[st.uid].prefill_s += time.perf_counter() - t0
         self.chunk_steps += 1
         self.prefill_tokens += valid
         st.next_start = start + valid
         if st.next_start < plen:
             return True
+        with self.tracer.span("readback", uid=st.uid):
+            first = int(first)
         # prefill complete: publish full prompt pages, activate for decode
-        if self.prefix_cache:
-            ps = self.page_size
-            for h in range(1, plen // ps + 1):
-                self._alloc.register(
-                    self._prefix_key(st.prompt, h * ps, st.gen),
-                    int(self._page_table[slot, h - 1]))
-        st.prefilling = False
-        self._active[slot] = True
-        self._last[slot, 0] = int(first)
-        self._emit(slot, int(first))
-        self.max_concurrency = max(self.max_concurrency,
-                                   int(self._active.sum()))
+        with self.tracer.span("emit", uid=st.uid):
+            if self.prefix_cache:
+                ps = self.page_size
+                for h in range(1, plen // ps + 1):
+                    self._alloc.register(
+                        self._prefix_key(st.prompt, h * ps, st.gen),
+                        int(self._page_table[slot, h - 1]))
+            st.prefilling = False
+            self._active[slot] = True
+            self._last[slot, 0] = first
+            self._emit(slot, first)
+            self.max_concurrency = max(self.max_concurrency,
+                                       int(self._active.sum()))
         return True
 
     def _step(self) -> bool:
         """One scheduler iteration: admit queued requests into free slots,
         advance one prefilling slot by one chunk, then one batched decode
-        step over all active slots. Returns True while work may remain."""
-        self._admit()
+        step over all active slots. Returns True while work may remain.
+        Each phase is a span: ``admit``, ``prefill_chunk`` (input upload
+        and dispatch), ``decode`` (the same), ``readback`` (waiting for the
+        step's tokens, and logits when sampling), ``emit`` (tokens,
+        sampling, retirement)."""
+        with self.tracer.span("admit"):
+            self._admit()
         self._advance_prefill()
         if not self._active.any():
             return self._has_work()
-        with self.tracer.span("decode", batch=int(self._active.sum())), \
-                jax.profiler.TraceAnnotation("engine_decode"):
+        with self.tracer.span("decode", batch=int(self._active.sum())):
             logits, greedy, self._cache = self._decode(
                 self.params, jnp.asarray(self._last), self._cache,
                 jnp.asarray(self._active), jnp.asarray(self._page_table),
                 self._stacked_policy())
         self.decode_steps += 1
-        greedy_np = np.asarray(greedy)
-        need_sampling = any(st is not None and not st.prefilling
-                            and st.gen.temperature > 0 for st in self._slots)
-        logits_np = np.asarray(logits) if need_sampling else None
-        for slot in range(self.n_slots):
-            st = self._slots[slot]
-            if st is None or st.prefilling:
-                continue
-            if st.gen.temperature > 0:
-                key = jax.random.fold_in(
-                    jax.random.fold_in(jax.random.PRNGKey(st.gen.seed),
-                                       st.uid), st.n_emitted)
-                tok = int(jax.random.categorical(
-                    key, jnp.asarray(logits_np[slot]) / st.gen.temperature))
-            else:
-                tok = int(greedy_np[slot])
-            self._last[slot, 0] = tok
-            self._emit(slot, tok)
+        with self.tracer.span("readback"):
+            greedy_np = np.asarray(greedy)
+            need_sampling = any(st is not None and not st.prefilling
+                                and st.gen.temperature > 0
+                                for st in self._slots)
+            logits_np = np.asarray(logits) if need_sampling else None
+        with self.tracer.span("emit"):
+            for slot in range(self.n_slots):
+                st = self._slots[slot]
+                if st is None or st.prefilling:
+                    continue
+                if st.gen.temperature > 0:
+                    key = jax.random.fold_in(
+                        jax.random.fold_in(jax.random.PRNGKey(st.gen.seed),
+                                           st.uid), st.n_emitted)
+                    tok = int(jax.random.categorical(
+                        key,
+                        jnp.asarray(logits_np[slot]) / st.gen.temperature))
+                else:
+                    tok = int(greedy_np[slot])
+                self._last[slot, 0] = tok
+                self._emit(slot, tok)
         return True
 
     def decode_hlo(self) -> str:
         """Optimized HLO text of the jitted decode step at the engine's
         current shapes — for auditing what the step runs on the device
-        (e.g. that the MoE kernel lowered natively)."""
+        (e.g. that the MoE kernel lowered natively). Each instruction's
+        ``metadata={op_name=...}`` carries the model's named scopes
+        (``embed``, ``attention``, ``moe``, ``lm_head``)."""
         return self._decode.lower(
             self.params, jnp.asarray(self._last), self._cache,
             jnp.asarray(self._active), jnp.asarray(self._page_table),
             self._stacked_policy()).compile().as_text()
+
+    def chunk_hlo(self) -> str:
+        """Optimized HLO text of the jitted prefill-chunk step, as
+        ``decode_hlo``."""
+        i32 = jnp.asarray(0, jnp.int32)
+        return self._chunk_insert.lower(
+            self.params, jnp.zeros((1, self.chunk_size), jnp.int32), i32,
+            i32, i32, self._cache, jnp.asarray(self._page_table),
+            self._slot_policy(GenerationConfig())).compile().as_text()
 
     # -- stats -----------------------------------------------------------
 

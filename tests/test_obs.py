@@ -243,7 +243,7 @@ def test_engine_timing_and_request_latency(served):
 # ---------------------------------------------------------------------------
 
 def test_chrome_trace_valid_json_with_nested_spans(tmp_path):
-    tr = SpanTracer()
+    tr = SpanTracer(enabled=True)
     with tr.span("outer", kind="test"):
         with tr.span("inner"):
             tr.instant("tick", n=1)
@@ -259,6 +259,55 @@ def test_chrome_trace_valid_json_with_nested_spans(tmp_path):
     assert outer["ts"] <= inner["ts"]
     assert inner["ts"] + inner["dur"] <= outer["ts"] + outer["dur"]
     assert outer["args"]["kind"] == "test"
+
+
+def test_paged_step_emits_phase_spans_in_order(served):
+    """One paged step's phases, in the order they open: the step, then
+    admission, the last prefill chunk with its token readback and emit,
+    then the batched decode with its readback and emit."""
+    cfg, params = served
+    eng = PagedEngine(cfg, params, n_slots=2, page_size=4, chunk_size=4,
+                      max_prompt_len=12, max_new_tokens=4, trace=True)
+    uid = eng.submit(np.arange(6, dtype=np.int32) % cfg.vocab_size,
+                     GenerationConfig(max_new_tokens=3))
+    eng.step()                         # first chunk: nothing to decode yet
+    n0 = len(eng.tracer.events())
+    eng.step()                         # last chunk, then decode
+    evs = sorted((e for e in eng.tracer.events()[n0:] if e["ph"] == "X"),
+                 key=lambda e: (e["ts"], -e["dur"]))
+    assert [e["name"] for e in evs] == [
+        "step", "admit", "prefill_chunk", "readback", "emit", "decode",
+        "readback", "emit"]
+    by = {e["name"]: e["args"] for e in evs}
+    assert by["prefill_chunk"]["uid"] == uid
+    assert by["prefill_chunk"]["start"] == 4
+    assert by["decode"] == {"batch": 1}
+    step = evs[0]
+    for e in evs[1:]:
+        assert step["ts"] <= e["ts"]
+        assert e["ts"] + e["dur"] <= step["ts"] + step["dur"]
+
+
+def test_spans_reach_the_profiler_with_the_buffer_off(served, tmp_path):
+    """Engine spans are profiler annotations whether or not the Chrome
+    buffer records; with ``trace=False`` (the default) it stays empty."""
+    import glob
+    from jax.profiler import ProfileData
+    cfg, params = served
+    eng = PagedEngine(cfg, params, n_slots=2, page_size=4, chunk_size=4,
+                      max_prompt_len=12, max_new_tokens=4)
+    eng.generate(_prompts(cfg, [3]), GenerationConfig(max_new_tokens=2))
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    with jax.profiler.trace(str(tmp_path), profiler_options=opts):
+        eng.generate(_prompts(cfg, [6, 3]), GenerationConfig(max_new_tokens=2))
+    assert eng.tracer.events() == []
+    path = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)[0]
+    names = {e.name for plane in ProfileData.from_file(path).planes
+             for line in plane.lines for e in line.events}
+    assert {"engine_step", "engine_admit", "engine_prefill_chunk",
+            "engine_decode", "engine_readback", "engine_emit",
+            "engine_submit", "engine_retire"} <= names
 
 
 def test_disabled_tracer_records_nothing(served):
@@ -306,32 +355,6 @@ def test_metrics_server_scrape(served):
         srv.stop()
     snap = parse_prometheus(text)
     assert snap.counters['repro_requests_total{state="finished"}'] == 1
-
-
-def test_obs_bench_schema_validator():
-    from repro.lint.bench_schema import validate_obs_bench
-    good = {
-        "bench": "obs_overhead", "unit": "us_per_decode_step", "note": "x",
-        "runs": [{
-            "timestamp": "2026-01-01T00:00:00Z",
-            "host": {"backend": "cpu", "devices": 1},
-            "smoke": False,
-            "rows": [{"engine": "continuous", "decode_steps": 10,
-                      "decode_us_on": 100.0, "decode_us_off": 98.0,
-                      "tok_s_on": 40.0, "tok_s_off": 41.0,
-                      "overhead_frac": 0.02}],
-        }],
-    }
-    assert validate_obs_bench(good) == []
-    bad = json.loads(json.dumps(good))
-    del bad["runs"][0]["rows"][0]["overhead_frac"]
-    bad["runs"][0]["rows"].append({"engine": "x", "decode_steps": 1,
-                                  "decode_us_on": 1, "decode_us_off": 1,
-                                  "tok_s_on": 1, "tok_s_off": 1,
-                                  "overhead_frac": 99.0})
-    errs = validate_obs_bench(bad)
-    assert any("missing key 'overhead_frac'" in e for e in errs)
-    assert any("credible" in e for e in errs)
 
 
 def test_serving_engine_row_schema_requires_timing():

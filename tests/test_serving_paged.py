@@ -8,6 +8,7 @@ attention reads are trimmed to the same static reduction widths the
 contiguous engines use, and exact-capacity MoE makes tokens independent of
 co-batched traffic — so a float32 cache reproduces greedy tokens exactly.
 """
+import re
 import warnings
 
 import jax
@@ -247,6 +248,56 @@ def test_paged_timed_admission(served):
     assert [r.submitted_s for r in res] == [0.0, 0.05]
     assert all(len(r.tokens) == 3 for r in res)
     assert all(r.finished_s >= r.submitted_s for r in res)
+
+
+@pytest.mark.parametrize("engine", ["paged", "continuous", "synchronized"])
+def test_lifecycle_stamps_are_ordered(served, engine):
+    """submitted <= admitted <= prefill_start <= first_token for every
+    request; with one slot, the second request waits behind the first."""
+    cfg, params = served
+    if engine == "paged":
+        eng = PagedEngine(cfg, params, n_slots=1, page_size=4, chunk_size=4,
+                          max_prompt_len=16, max_new_tokens=4)
+    elif engine == "continuous":
+        eng = ContinuousBatchingEngine(cfg, params, n_slots=1,
+                                       max_prompt_len=16, max_new_tokens=4)
+    else:
+        eng = ServingEngine(cfg, params, batch_size=1, max_prompt_len=16,
+                            max_new_tokens=4)
+    res = eng.generate(_prompts(cfg, [14, 5, 9]),
+                       GenerationConfig(max_new_tokens=3))
+    for r in res:
+        assert (r.submitted_s <= r.admitted_s <= r.prefill_start_s
+                <= r.first_token_s <= r.finished_s)
+    first, second = res[0], res[1]
+    assert second.prefill_start_s - second.submitted_s > 0
+    assert second.admitted_s >= first.finished_s
+
+
+def test_paged_prefill_starts_at_first_chunk(served):
+    """A paged request is admitted when it gets a slot and pages, and its
+    prefill starts when its first chunk is dispatched: a short prompt
+    admitted beside a long one waits for the lane."""
+    cfg, params = served
+    eng = PagedEngine(cfg, params, n_slots=2, page_size=4, chunk_size=4,
+                      max_prompt_len=16, max_new_tokens=4)
+    res = eng.generate(_prompts(cfg, [14, 5]),
+                       GenerationConfig(max_new_tokens=2))
+    long_, short = res
+    assert short.admitted_s < long_.first_token_s <= short.prefill_start_s
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk"])
+def test_compiled_steps_carry_model_scopes(served, program):
+    """Each compiled step names its parts in the op_name metadata, so a
+    profiler trace can be split by model layer."""
+    cfg, params = served
+    eng = PagedEngine(cfg, params, n_slots=2, page_size=4, chunk_size=4,
+                      max_prompt_len=16, max_new_tokens=4)
+    text = eng.decode_hlo() if program == "decode" else eng.chunk_hlo()
+    parts = {part for name in re.findall(r'op_name="([^"]*)"', text)
+             for part in name.split("/")}
+    assert {"embed", "attention", "moe", "route", "lm_head"} <= parts
 
 
 def test_paged_rejects_oversized_and_unsupported(served):
