@@ -212,10 +212,36 @@ def _fused_pipeline_block(block_c: int, capacity: int) -> int:
     return min(block_c, capacity)
 
 
+EXPERT_WEIGHTS = ("w1", "w3", "w2")
+
+
+def fused_pipeline_on(fused_pipeline: Optional[bool], n_tokens: int,
+                      n_experts: int, use_kernel: bool = False) -> bool:
+    """Resolve the ``fused_pipeline`` hint: ``None`` defers to
+    ``core.dispatch.prefer_fused_pipeline`` (per shape and backend)."""
+    if fused_pipeline is None:
+        return dispatch_mod.prefer_fused_pipeline(n_tokens, n_experts,
+                                                  use_kernel=use_kernel)
+    return bool(fused_pipeline)
+
+
+def reads_layer_stack(w1_shape, n_tokens: int, *,
+                      fused_pipeline: Optional[bool] = None,
+                      use_kernel: bool = False) -> bool:
+    """Whether the MoE forward can hand its kernel the layer-stacked
+    expert weights (``w1_shape`` is ``(L, Es, d, f)``) and a layer index
+    instead of one layer's slice. Only the streamed fused kernel indexes a
+    layer itself, and only where ``f`` needs no padding to its 128-wide
+    neuron tiles: padding would copy the whole stack on every call."""
+    f = w1_shape[-1]
+    return f % min(128, f) == 0 and fused_pipeline_on(
+        fused_pipeline, n_tokens, w1_shape[-3], use_kernel)
+
+
 def _fused_pipeline_dispatch(params, x, cfg, pairs: SubExpertPairs, p: int,
                              capacity: int, mode_grouped: bool,
                              block_c: int = 128, block_f: int = 128,
-                             streamed: bool = True):
+                             streamed: bool = True, layer=None):
     """The single fused Pallas pipeline (ROADMAP item 4): the kernel
     consumes the DispatchPlan directly — sort permutation + segment counts
     — gathering token rows from the flat (T, d) array, running the
@@ -228,12 +254,16 @@ def _fused_pipeline_dispatch(params, x, cfg, pairs: SubExpertPairs, p: int,
     ``mode_grouped`` (P > 1): one row per ORIGINAL pair, weights fused at
     kernel level via ``p_factor`` BlockSpec indexing. Otherwise rows are
     sub-expert pairs against the weights' native expert axis. Overflow is
-    reported in SUB-pair units on both layouts."""
+    reported in SUB-pair units on both layouts.
+
+    ``layer``: the expert weights are layer-stacked and the kernel reads
+    that layer of them in place."""
     from ..kernels import ops as kops
     T, d = x.shape
     bc = _fused_pipeline_block(block_c, capacity)
+    n_sub = params["w1"].shape[-3]
     if mode_grouped and p > 1:
-        E = params["w1"].shape[0] // p
+        E = n_sub // p
         fused = dispatch_mod.fuse_sub_pairs(pairs, p)
         K = fused.group.shape[1]
         plan = dispatch_mod.dispatch_plan(fused.group, fused.keep,
@@ -243,7 +273,7 @@ def _fused_pipeline_dispatch(params, x, cfg, pairs: SubExpertPairs, p: int,
         overflow = _sub_pair_overflow(plan, pairs, fused, capacity)
         p_factor, n_minor_start = p, None
     else:
-        E = params["w1"].shape[0]
+        E = n_sub
         K = pairs.idx.shape[1]
         plan = dispatch_mod.dispatch_plan(pairs.idx, pairs.keep,
                                           n_groups=E, capacity=capacity)
@@ -257,7 +287,7 @@ def _fused_pipeline_dispatch(params, x, cfg, pairs: SubExpertPairs, p: int,
         x, params["w1"], params["w3"], params["w2"], plan.group_offsets,
         cf, cm, tok_sorted, w_sorted, capacity=capacity, p_factor=p_factor,
         n_minor_start=n_minor_start, block_c=block_c, block_f=block_f,
-        streamed=streamed)
+        streamed=streamed, layer=layer)
     return y, overflow
 
 
@@ -268,7 +298,7 @@ def moe_forward_dispatch(params, x, cfg, pairs: Optional[SubExpertPairs] = None,
                          return_overflow: bool = False,
                          mode_grouped: bool = False,
                          fused_pipeline: Optional[bool] = None,
-                         fused_streamed: bool = True):
+                         fused_streamed: bool = True, layer=None):
     """Sort-based gather -> batched expert GEMM -> gather back. Exact w.r.t.
     the reference whenever no token exceeds capacity.
 
@@ -302,9 +332,14 @@ def moe_forward_dispatch(params, x, cfg, pairs: Optional[SubExpertPairs] = None,
     ``return_overflow``: also return the scalar count of kept pairs dropped
     by capacity overflow (see ``dispatch_indices``). Always in sub-pair
     units, on every path.
+
+    ``layer``: ``params``' expert weights are the layer-stacked
+    ``(L, Es, d, f)`` arrays and the streamed fused kernel reads that
+    layer of them in place. Only for calls ``reads_layer_stack`` admits;
+    every other path takes one layer's weights.
     """
     T, d = x.shape
-    E = params["w1"].shape[0]
+    E = params["w1"].shape[-3]
     if pairs is None:
         pairs = route_plain(params, x, cfg, n_experts=E)
     K = pairs.idx.shape[1]
@@ -312,13 +347,11 @@ def moe_forward_dispatch(params, x, cfg, pairs: Optional[SubExpertPairs] = None,
         capacity = capacity_for(T, K, E, capacity_factor)
 
     p = _pairs_partition_p(pairs)
-    if fused_pipeline is None:
-        fused_pipeline = dispatch_mod.prefer_fused_pipeline(
-            T, E, use_kernel=use_kernel)
-    if fused_pipeline:
+    if fused_pipeline_on(fused_pipeline, T, E, use_kernel):
         y, overflow = _fused_pipeline_dispatch(
             params, x, cfg, pairs, p, capacity,
-            mode_grouped=mode_grouped and p > 1, streamed=fused_streamed)
+            mode_grouped=mode_grouped and p > 1, streamed=fused_streamed,
+            layer=layer)
         out = y.astype(x.dtype) + _shared_out(params, x)
         return (out, overflow) if return_overflow else out
 

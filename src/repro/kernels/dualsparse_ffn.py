@@ -102,7 +102,8 @@ def fused_moe_pipeline_kernel_spec(T: int, d: int, f: int, E: int,
                                    n_minor_start: int | None = None,
                                    block_c: int = 128,
                                    block_f: int = 128,
-                                   streamed: bool = True) -> KernelSpec:
+                                   streamed: bool = True,
+                                   n_layers: int = 0) -> KernelSpec:
     """Static launch description of ``fused_moe_pipeline_pallas``.
 
     ``streamed=True`` (production): the per-pair maps ride in SMEM via
@@ -116,12 +117,23 @@ def fused_moe_pipeline_kernel_spec(T: int, d: int, f: int, E: int,
     (T, d) activation/output arrays VMEM-resident — kept as the
     bit-exactness oracle for the streamed kernel, the bench comparison
     point, and the lint negative test (it MUST blow the VMEM budget at
-    prefill scale)."""
+    prefill scale).
+
+    ``n_layers > 0`` (streamed only): the stacked launch. The weights are
+    the layer-stacked ``(n_layers, E*p_factor, d, f)`` arrays, the
+    ``layer`` index rides first in SMEM, and each weight block squeezes
+    the layer axis (a leading 1 here, ``pl.squeezed`` in the launch): the
+    same tiles, read from one layer of the stack in place."""
     g = _resolve_blocks(capacity, f, p_factor, n_minor_start,
                         block_c, block_f)
     dt = dtype_name(dtype)
     map_space = "smem" if streamed else "vmem"
+    layer_axis = (1,) if n_layers else ()
     blocks = [
+        BlockUse("layer", (1,), "int32", "in", streamed=False,
+                 control=True, space="smem"),
+    ] if n_layers else []
+    blocks += [
         BlockUse("group_offsets", (E,), "int32", "in", streamed=False,
                  control=True, space=map_space),
         BlockUse("counts_full", (E,), "int32", "in", streamed=False,
@@ -138,9 +150,9 @@ def fused_moe_pipeline_kernel_spec(T: int, d: int, f: int, E: int,
         blocks += [
             BlockUse("x", (T,) + row, dt, "in", streamed=False,
                      space="any", dma_buffers=2),
-            BlockUse("w1", (1, d, g["block_f"]), dt, "in"),
-            BlockUse("w3", (1, d, g["block_f"]), dt, "in"),
-            BlockUse("w2", (1, g["block_f"], d), dt, "in"),
+            BlockUse("w1", layer_axis + (1, d, g["block_f"]), dt, "in"),
+            BlockUse("w3", layer_axis + (1, d, g["block_f"]), dt, "in"),
+            BlockUse("w2", layer_axis + (1, g["block_f"], d), dt, "in"),
             BlockUse("out", (T,) + row, "float32", "out", streamed=False,
                      space="any", dma_buffers=1),
             BlockUse("x_tiles", (2 * g["block_c"],) + row, dt, "scratch"),
@@ -150,6 +162,7 @@ def fused_moe_pipeline_kernel_spec(T: int, d: int, f: int, E: int,
                      "scratch"),
         ]
     else:
+        assert not n_layers, "the stacked launch is streamed only"
         blocks += [
             BlockUse("x", (T, d), dt, "in", streamed=False),
             BlockUse("w1", (1, d, g["block_f"]), dt, "in"),
@@ -162,7 +175,7 @@ def fused_moe_pipeline_kernel_spec(T: int, d: int, f: int, E: int,
     grid = (E, g["Cp"] // g["block_c"], g["n_f"])
     meta = dict(g, E=E, C=capacity, d=d, f=f, T=T, capacity=capacity,
                 n_pairs_padded=n_pairs_padded, virtual_f=g["fp"] * p_factor,
-                streamed=streamed)
+                streamed=streamed, n_layers=n_layers)
     return KernelSpec("fused_moe_pipeline", grid, tuple(blocks), meta)
 
 
@@ -525,7 +538,7 @@ def fused_moe_pipeline_pallas(x, w1, w3, w2, group_offsets, counts_full,
                               capacity: int, p_factor: int = 1,
                               n_minor_start: int | None = None,
                               block_c: int = 128, block_f: int = 128,
-                              streamed: bool = True,
+                              streamed: bool = True, layer=None,
                               interpret: bool = True):
     """Fused dispatch -> grouped SwiGLU -> weighted combine (one kernel).
 
@@ -560,9 +573,18 @@ def fused_moe_pipeline_pallas(x, w1, w3, w2, group_offsets, counts_full,
     (the streamed kernel's bit-exactness oracle and the lint negative
     test; interpret mode only). Both produce identical bits;
     ``interpret=True`` validates the block/skip/DMA logic on CPU.
+
+    ``layer`` (a traced int32 scalar; streamed only): w1/w3/w2 are the
+    layer-stacked ``(L, E*p_factor, d, f)`` / ``(L, E*p_factor, f, d)``
+    arrays and the kernel reads layer ``layer``'s tiles straight from
+    them — the index rides in scalar prefetch and the weight blocks squeeze
+    the layer axis — so a layer scan hands over the whole stacks instead
+    of a sliced copy. Same tiles, same order, same bits. ``f`` must be a
+    multiple of ``block_f``: padding would copy the whole stack per call.
     """
     T, d = x.shape
-    Es, _, f = w1.shape
+    Es, _, f = w1.shape[-3:]
+    n_layers = 0 if layer is None else w1.shape[0]
     E = group_offsets.shape[0]
     assert Es == E * p_factor, (
         f"weights carry {Es} sub-experts; plan has {E} groups x "
@@ -573,7 +595,8 @@ def fused_moe_pipeline_pallas(x, w1, w3, w2, group_offsets, counts_full,
     spec = fused_moe_pipeline_kernel_spec(
         T, d, f, E, Np, capacity=capacity, dtype=x.dtype,
         p_factor=p_factor, n_minor_start=n_minor_start,
-        block_c=block_c, block_f=block_f, streamed=streamed)
+        block_c=block_c, block_f=block_f, streamed=streamed,
+        n_layers=n_layers)
     g = spec.meta
     block_c, block_f = g["block_c"], g["block_f"]
     pf, nf_sub, n_f = g["pad_f"], g["nf_sub"], g["n_f"]
@@ -583,6 +606,9 @@ def fused_moe_pipeline_pallas(x, w1, w3, w2, group_offsets, counts_full,
         raise NotImplementedError(
             "the resident fused kernel (streamed=False) is an interpret-mode "
             "oracle only; its one-row VMEM slices do not lower on TPU")
+    if n_layers and pf:
+        raise ValueError(f"stacked weights need f % block_f == 0 (f={f}, "
+                         f"block_f={block_f}); slice the layer instead")
     if pf:
         w1 = jnp.pad(w1, ((0, 0), (0, 0), (0, pf)))
         w3 = jnp.pad(w3, ((0, 0), (0, 0), (0, pf)))
@@ -601,22 +627,39 @@ def fused_moe_pipeline_pallas(x, w1, w3, w2, group_offsets, counts_full,
             _fused_pipeline_streamed_kernel, T=T, d=d, block_c=block_c,
             block_f=block_f, n_minor_start=n_minor_start, n_f=n_f,
             n_c=n_c, n_blocks=E * n_c, E=E)
+        prefetch = operands[:5]
+        layer_block = ()
+        if n_layers:
+            # the layer index is prefetched first and read by the weight
+            # index maps only; the kernel body never sees it
+            prefetch = (jnp.reshape(layer, (1,)).astype(jnp.int32),
+                        *prefetch)
+            layer_block = (pl.squeezed,)
+            body = kernel
 
-        # index maps receive the 5 scalar-prefetch refs as trailing args
-        def w13_map(e, c, f, *_refs):
-            return (e * p_factor + f // nf_sub, 0, f % nf_sub)
+            def kernel(layer_ref, *refs):
+                return body(*refs)
 
-        def w2_map(e, c, f, *_refs):
-            return (e * p_factor + f // nf_sub, f % nf_sub, 0)
+        def at_layer(refs):
+            return (refs[0][0],) if n_layers else ()
+
+        # index maps receive the scalar-prefetch refs as trailing args
+        def w13_map(e, c, f, *refs):
+            return at_layer(refs) + (e * p_factor + f // nf_sub, 0,
+                                     f % nf_sub)
+
+        def w2_map(e, c, f, *refs):
+            return at_layer(refs) + (e * p_factor + f // nf_sub,
+                                     f % nf_sub, 0)
 
         grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=5,
+            num_scalar_prefetch=len(prefetch),
             grid=grid,
             in_specs=[
                 pl.BlockSpec(memory_space=pl.ANY),           # x (HBM)
-                pl.BlockSpec((1, d, block_f), w13_map),
-                pl.BlockSpec((1, d, block_f), w13_map),
-                pl.BlockSpec((1, block_f, d), w2_map),
+                pl.BlockSpec(layer_block + (1, d, block_f), w13_map),
+                pl.BlockSpec(layer_block + (1, d, block_f), w13_map),
+                pl.BlockSpec(layer_block + (1, block_f, d), w2_map),
             ],
             out_specs=pl.BlockSpec(memory_space=pl.ANY),     # out (HBM)
             scratch_shapes=[
@@ -633,7 +676,7 @@ def fused_moe_pipeline_pallas(x, w1, w3, w2, group_offsets, counts_full,
             out_shape=jax.ShapeDtypeStruct((T, R, L), jnp.float32),
             interpret=interpret,
             name="fused_moe_pipeline",
-        )(*operands[:5], x.reshape(T, R, L), *operands[6:])
+        )(*prefetch, x.reshape(T, R, L), *operands[6:])
         return out.reshape(T, d).astype(x.dtype)
 
     kernel = functools.partial(

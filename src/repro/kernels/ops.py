@@ -40,7 +40,7 @@ def fused_moe_pipeline(x, w1, w3, w2, group_offsets, counts_full,
                        counts_major, tok_sorted, combine_sorted,
                        capacity: int, p_factor: int = 1, n_minor_start=None,
                        block_c: int = 128, block_f: int = 128,
-                       streamed: bool = True):
+                       streamed: bool = True, layer=None):
     """Fused dispatch -> grouped SwiGLU -> weighted combine in ONE Pallas
     kernel: gathers token rows from the flat (T, d) activation array
     through the sort permutation, runs the mode-ordered dual-sparse FFN
@@ -52,14 +52,15 @@ def fused_moe_pipeline(x, w1, w3, w2, group_offsets, counts_full,
     in ANY (HBM) memory with explicit double-buffered DMA, so the VMEM
     working set is independent of T (prefill-safe). ``streamed=False``
     keeps the whole-array-resident PR-6 layout (bit-identical output,
-    CPU interpreter only).
+    CPU interpreter only). With ``layer`` (traced scalar) the weights are
+    the layer-stacked arrays and the kernel reads that layer in place.
     See kernels.dualsparse_ffn.fused_moe_pipeline_pallas for the
     contract; ``core.dispatch.sorted_pair_arrays`` builds the pair maps."""
     return fused_moe_pipeline_pallas(
         x, w1, w3, w2, group_offsets, counts_full, counts_major,
         tok_sorted, combine_sorted, capacity=capacity, p_factor=p_factor,
         n_minor_start=n_minor_start, block_c=block_c, block_f=block_f,
-        streamed=streamed, interpret=_interpret())
+        streamed=streamed, layer=layer, interpret=_interpret())
 
 
 @functools.partial(jax.jit, static_argnames=("p_factor", "n_minor_start",

@@ -364,18 +364,19 @@ def _kernel_spec_entries() -> List[LintEntry]:
         return Artifacts(kernel_specs=(grouped_swiglu_kernel_spec(
             E, cap, d, fsub, dtype=jnp.bfloat16, p_factor=1),))
 
-    def fused_trace(T, *, d=d, f=fsub, E=E, top_k=top_k):
+    def fused_trace(T, *, d=d, f=fsub, E=E, top_k=top_k, n_layers=48):
         # production fused path at P>1 is mode-grouped: ONE pair per
         # (token, original expert), so the scalar-prefetch maps carry
         # T*top_k entries (+ one block of padding) — half the sub-pair
         # layout at P=2, which is what keeps them inside the SMEM budget
-        # at prefill scale
+        # at prefill scale. The layer scans launch it on the layer-stacked
+        # weights (published depth; the layer index rides in SMEM).
         def trace():
             cap = capacity_for(T, top_k * P, E, 2.0)
             n_pairs = T * top_k + 128
             return Artifacts(kernel_specs=(fused_moe_pipeline_kernel_spec(
                 T, d, f, E, n_pairs, capacity=cap, dtype=jnp.bfloat16,
-                p_factor=P),))
+                p_factor=P, n_layers=n_layers),))
         return trace
 
     return [
@@ -395,7 +396,7 @@ def _kernel_spec_entries() -> List[LintEntry]:
         # top_k=2) — the acceptance shape for the streamed residency model
         LintEntry(name="kernel/fused_pipeline/prefill_8k_wide", meta={},
                   _trace=fused_trace(8192, d=4096, f=14336 // P, E=64,
-                                     top_k=2)),
+                                     top_k=2, n_layers=32)),
     ]
 
 

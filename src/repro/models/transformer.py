@@ -152,7 +152,7 @@ def _policy_of(dist: Optional[DistContext]):
 
 
 def _moe_forward(p, x, cfg, dist: Optional[DistContext], aux: bool = False,
-                 collect: bool = False):
+                 collect: bool = False, layer=None):
     """MoE layer forward under ``dist.policy`` (default ``NoDrop``).
 
     Returns ``(y, aux_loss, overflow)``: aux_loss is None unless ``aux``
@@ -163,7 +163,10 @@ def _moe_forward(p, x, cfg, dist: Optional[DistContext], aux: bool = False,
     ``collect``: the third return is instead the per-layer ``repro.obs``
     stats dict (kept-pair expert_load histogram over sub-expert ids plus
     kept_full/kept_major/dropped_pairs/overflow_pairs) — same routing,
-    bit-identical ``y``."""
+    bit-identical ``y``.
+
+    ``layer``: ``p``'s expert weights are the whole layer-stacked arrays
+    (see ``_expert_stack_xs``) and this is the layer to run."""
     B, S, d = x.shape
     aux_val = None
     if aux:
@@ -192,9 +195,9 @@ def _moe_forward(p, x, cfg, dist: Optional[DistContext], aux: bool = False,
         capacity=policy.dispatch_capacity(xt.shape[0]),
         use_kernel=policy.use_kernel, return_overflow=True,
         mode_grouped=policy.kernel_mode_grouping,
-        fused_pipeline=getattr(policy, "fused_pipeline", None))
+        fused_pipeline=getattr(policy, "fused_pipeline", None), layer=layer)
     if collect:
-        n_sub = p["w1"].shape[0]
+        n_sub = p["w1"].shape[-3]
         p_factor = pairs.idx.shape[1] // pairs.modes.shape[1]
         kf, km, dr = drop_mod.sub_pair_outcome_counts(pairs.keep, p_factor)
         stats = {"expert_load": gating.expert_histogram(pairs.idx, n_sub,
@@ -205,7 +208,7 @@ def _moe_forward(p, x, cfg, dist: Optional[DistContext], aux: bool = False,
     return y.reshape(B, S, d), aux_val, overflow
 
 
-def _ffn_block(bp, x, cfg, dist, collect_stats):
+def _ffn_block(bp, x, cfg, dist, collect_stats, layer=None):
     """ln2 + MoE (or dense MLP) + residual, under the ``moe`` (``mlp``)
     named scope. Returns (x, moe_overflow or obs stats dict)."""
     overflow = jnp.zeros((), jnp.int32)
@@ -213,7 +216,7 @@ def _ffn_block(bp, x, cfg, dist, collect_stats):
         h = L.rms_norm(x, bp["ln2"], cfg.norm_eps)
         if "moe" in bp:
             y, _, overflow = _moe_forward(bp["moe"], h, cfg, dist,
-                                          collect=collect_stats)
+                                          collect=collect_stats, layer=layer)
             x = x + y
         else:
             x = x + L.apply_mlp(bp["mlp"], h, cfg.mlp_kind)
@@ -223,7 +226,7 @@ def _ffn_block(bp, x, cfg, dist, collect_stats):
 def block_forward(bp, x, positions, cfg, *, window: int = 0,
                   dist: Optional[DistContext] = None, capture_cap: int = 0,
                   cache_dtype=jnp.bfloat16, with_aux: bool = False,
-                  collect_stats: bool = False):
+                  collect_stats: bool = False, layer=None):
     """Full-sequence block forward (train / prefill). With capture_cap the
     return is (x, cache_layer, moe_overflow) for the prefill->decode
     handoff (with ``collect_stats`` the third slot is the per-layer obs
@@ -254,7 +257,7 @@ def block_forward(bp, x, positions, cfg, *, window: int = 0,
             h = L.rms_norm(x, bp["ln2"], cfg.norm_eps)
             y, aux, _ = _moe_forward(bp["moe"], h, cfg, dist, aux=True)
         return x + y, aux
-    x, overflow = _ffn_block(bp, x, cfg, dist, collect_stats)
+    x, overflow = _ffn_block(bp, x, cfg, dist, collect_stats, layer)
     if with_aux:
         return x, jnp.zeros(())
     return (x, cache_layer, overflow) if capture_cap else x
@@ -263,7 +266,7 @@ def block_forward(bp, x, positions, cfg, *, window: int = 0,
 def block_decode(bp, x, cache_layer, pos, cfg, *, window: int = 0,
                  dist: Optional[DistContext] = None, layout=None,
                  page_table=None, write_mask=None, read_len=None,
-                 collect_stats: bool = False):
+                 collect_stats: bool = False, layer=None):
     """One-token decode. cache_layer is this layer's cache dict slice.
     Returns (x, cache_layer, moe_overflow) — or the per-layer obs stats
     dict in the third slot under ``collect_stats``. ``layout``/
@@ -286,7 +289,7 @@ def block_decode(bp, x, cache_layer, pos, cfg, *, window: int = 0,
                 layout=layout, page_table=page_table, write_mask=write_mask,
                 read_len=read_len)
         x = x + y
-    x, overflow = _ffn_block(bp, x, cfg, dist, collect_stats)
+    x, overflow = _ffn_block(bp, x, cfg, dist, collect_stats, layer)
     return x, cache_layer, overflow
 
 
@@ -328,6 +331,37 @@ def _positions_for(cfg, B, S, offset=0):
     return pos
 
 
+def _expert_stack_xs(blocks, x, dist: Optional[DistContext], *,
+                     split: bool = True):
+    """``(xs, rejoin)`` for a scan over the stacked ``blocks``: ``rejoin``
+    turns the body's slice of ``xs`` into ``(block params, layer)``.
+
+    Where each layer's MoE runs the streamed fused kernel, which reads its
+    layer straight from the layer-stacked expert weights
+    (``moe.reads_layer_stack``), the expert stacks leave ``xs`` — scanning
+    them would slice, i.e. copy, every stack on every call — and are
+    closed over whole, with the layer index in ``xs`` instead. Elsewhere
+    (``split`` False, no MoE, S-ETP, any other MoE path) ``xs`` is
+    ``blocks`` and the layer is None."""
+    moe = blocks.get("moe")
+    policy = _policy_of(dist)
+    if not (split and moe is not None
+            and (dist is None or dist.moe_impl != "setp")
+            and moe_mod.reads_layer_stack(
+                moe["w1"].shape, x.shape[0] * x.shape[1],
+                fused_pipeline=getattr(policy, "fused_pipeline", None),
+                use_kernel=policy.use_kernel)):
+        return blocks, lambda bp: (bp, None)
+    stacks = {k: moe[k] for k in moe_mod.EXPERT_WEIGHTS}
+    rest = {k: v for k, v in moe.items() if k not in stacks}
+
+    def rejoin(xs):
+        bp, layer = xs
+        return {**bp, "moe": {**bp["moe"], **stacks}}, layer
+    layers = jnp.arange(moe["w1"].shape[0], dtype=jnp.int32)
+    return ({**blocks, "moe": rest}, layers), rejoin
+
+
 def stack_forward(params, x, positions, cfg, *, window: int = 0,
                   dist: Optional[DistContext] = None, capture_cap: int = 0,
                   cache_dtype=jnp.bfloat16, with_aux: bool = False,
@@ -357,18 +391,24 @@ def stack_forward(params, x, positions, cfg, *, window: int = 0,
         fwd = jax.checkpoint(fwd, policy=policy)
 
     res_spec = _residual_spec(dist, x.shape[1], cfg.family)
+    # only the prefill splits off the expert stacks: a forward without
+    # capture may be differentiated, and its stacks' gradients stay
+    # per-layer slices
+    xs, rejoin = _expert_stack_xs(params["blocks"], x, dist,
+                                  split=bool(capture_cap))
 
-    def body(h, bp):
+    def body(h, xs):
+        bp, layer = rejoin(xs)
         h = _maybe_constrain(h, dist, res_spec)
         if capture_cap:
-            h2, cl, of = fwd(bp, h, positions)
+            h2, cl, of = fwd(bp, h, positions, layer=layer)
             return h2, (cl, of)
         out = fwd(bp, h, positions)
         if with_aux:
             return out
         return out, None
 
-    x, caches = jax.lax.scan(body, x, params["blocks"])
+    x, caches = jax.lax.scan(body, x, xs)
     if capture_cap:
         layers, ofs = caches
         cache = ObsCache({"layers": layers})
@@ -450,18 +490,20 @@ def stack_decode(params, x, cache, pos, cfg, *, window: int = 0,
     # STRUCTURE (the "metrics" key), never by leaf values — so metric
     # value churn can't retrace
     collect = "metrics" in cache
+    blocks, rejoin = _expert_stack_xs(params["blocks"], x, dist)
 
     def body(h, xs):
         bp, cl = xs
+        bp, layer = rejoin(bp)
         h, cl, of = block_decode(bp, h, cl, pos, cfg, window=window,
                                  dist=dist, layout=layout,
                                  page_table=page_table,
                                  write_mask=write_mask, read_len=read_len,
-                                 collect_stats=collect)
+                                 collect_stats=collect, layer=layer)
         return h, (cl, of)
 
     x, (new_layers, ofs) = jax.lax.scan(
-        body, x, (params["blocks"], cache["layers"]))
+        body, x, (blocks, cache["layers"]))
     new = ObsCache({"layers": new_layers})
     if collect:                   # device-side accumulation, no host sync
         new["metrics"] = cache["metrics"].accumulate(ofs)
@@ -610,7 +652,7 @@ def decode_step(params, token, cache, cfg, *, window: int = 0,
 def chunk_block(bp, x, cache_layer, slot, start, valid_len, cfg, *,
                 layout, page_table=None, read_len=None,
                 dist: Optional[DistContext] = None,
-                collect_stats: bool = False):
+                collect_stats: bool = False, layer=None):
     """One block over a (1,C,d) prompt chunk of a single slot, appending its
     K/V into the decode cache. Returns (x, cache_layer, moe_overflow) —
     obs stats dict in the third slot under ``collect_stats``."""
@@ -620,7 +662,7 @@ def chunk_block(bp, x, cache_layer, slot, start, valid_len, cfg, *,
             bp["attn"], h, cache_layer, slot, start, valid_len, cfg,
             layout=layout, page_table=page_table, read_len=read_len)
         x = x + y
-    x, overflow = _ffn_block(bp, x, cfg, dist, collect_stats)
+    x, overflow = _ffn_block(bp, x, cfg, dist, collect_stats, layer)
     return x, cache_layer, overflow
 
 
@@ -648,17 +690,19 @@ def chunk_step(params, tokens, slot, start, valid_len, cache, cfg, *,
         x = L.embed(params["embed"], tokens)
 
     collect = "metrics" in cache  # static structural gate, as stack_decode
+    blocks, rejoin = _expert_stack_xs(params["blocks"], x, dist)
 
     def body(h, xs):
         bp, cl = xs
+        bp, layer = rejoin(bp)
         h, cl, of = chunk_block(bp, h, cl, slot, start, valid_len, cfg,
                                 layout=layout, page_table=page_table,
                                 read_len=read_len, dist=dist,
-                                collect_stats=collect)
+                                collect_stats=collect, layer=layer)
         return h, (cl, of)
 
     x, (new_layers, ofs) = jax.lax.scan(
-        body, x, (params["blocks"], cache["layers"]))
+        body, x, (blocks, cache["layers"]))
     with jax.named_scope("lm_head"):
         x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
         logits = L.unembed(params["embed"], x)

@@ -323,6 +323,31 @@ def test_smem_pass_budget_and_clean():
     assert pallas_passes.check_smem_footprint(res, "resident") == []
 
 
+def test_stacked_launch_spec_reads_one_layer_in_place():
+    """The layer scans' launch on layer-stacked weights: the layer index
+    is one more SMEM control entry, each weight block squeezes the layer
+    axis, and the grid, VMEM working set and every Pallas pass's findings
+    are those of the launch on one layer's weights."""
+    args = (256, 2048, 384, 128, 256 * 16 + 128)
+    kw = dict(capacity=64, dtype=jnp.bfloat16, p_factor=2)
+    flat = fused_moe_pipeline_kernel_spec(*args, **kw)
+    stacked = fused_moe_pipeline_kernel_spec(*args, n_layers=48, **kw)
+    one = {b.name: b for b in flat.blocks}
+    by = {b.name: b for b in stacked.blocks}
+    assert by["layer"].space == "smem" and by["layer"].control
+    for w in ("w1", "w3", "w2"):
+        assert by[w].shape == (1,) + one[w].shape
+    assert stacked.grid == flat.grid
+    assert stacked.vmem_bytes() == flat.vmem_bytes()
+    assert stacked.smem_bytes() == flat.smem_bytes() + 4
+    for check in (pallas_passes.check_vmem_footprint,
+                  pallas_passes.check_smem_footprint,
+                  pallas_passes.check_dma_streaming,
+                  pallas_passes.check_mxu_alignment,
+                  pallas_passes.check_grid_coverage):
+        assert check(stacked, "e") == check(flat, "e")
+
+
 def test_dma_pass_requires_staged_double_buffering():
     spec = fused_moe_pipeline_kernel_spec(
         256, 2048, 384, 128, 256 * 16 + 128, capacity=64,

@@ -8,6 +8,7 @@ attention reads are trimmed to the same static reduction widths the
 contiguous engines use, and exact-capacity MoE makes tokens independent of
 co-batched traffic — so a float32 cache reproduces greedy tokens exactly.
 """
+import dataclasses
 import re
 import warnings
 
@@ -17,6 +18,7 @@ import numpy as np
 import pytest
 
 from repro.configs import get_config
+from repro.core import moe as moe_mod
 from repro.models import attention as A
 from repro.models import model as M
 from repro.models import transformer as T
@@ -298,6 +300,110 @@ def test_compiled_steps_carry_model_scopes(served, program):
     parts = {part for name in re.findall(r'op_name="([^"]*)"', text)
              for part in name.split("/")}
     assert {"embed", "attention", "moe", "route", "lm_head"} <= parts
+
+
+# ---------------------------------------------------------------------------
+# The fused kernel reads its layer straight from the layer-stacked experts
+# ---------------------------------------------------------------------------
+
+def _fused_dist(cfg, params, p):
+    """Prepared params and a dist whose MoE runs the streamed fused kernel
+    at partition p: NoDrop at p=1, calibrated mode-grouped 2T-Drop at p=2
+    (so MAJOR-only rows exist and skip minor tiles)."""
+    from repro.core.policy import NoDrop, make_policy
+    from repro.launch.mesh import make_host_mesh
+    hints = dict(use_kernel=True, fused_pipeline=True)
+    pol = NoDrop(**hints) if p == 1 else \
+        make_policy("2t", cfg.dualsparse, drop_target=0.25, **hints)
+    calib = jax.random.normal(jax.random.PRNGKey(1), (64, cfg.d_model))
+    prepared, pol = pol.prepare(params, cfg, calib)
+    assert prepared["blocks"]["moe"]["w1"].shape[1] == cfg.n_experts * p
+    return prepared, T.DistContext(mesh=make_host_mesh(1),
+                                   moe_impl="dispatch", policy=pol)
+
+
+def _serve_recorded(cfg, params, dist):
+    """Serve mixed prompts through a paged engine, recording every decode
+    call's logits and greedy tokens and every chunk call's first token;
+    also one prompt chunk's logits from the chunk program's model step.
+    Returns (records, chunk logits, final cache)."""
+    eng = PagedEngine(cfg, params, dist=dist, n_slots=2, page_size=4,
+                      chunk_size=4, max_prompt_len=12, max_new_tokens=4,
+                      cache_dtype=jnp.float32)
+    seen = []
+    decode, chunk = eng._decode, eng._chunk_insert
+
+    def record(fn, n_out):
+        def call(*args):
+            out = fn(*args)
+            seen.append([np.asarray(o) for o in out[:n_out]])
+            return out
+        return call
+    eng._decode, eng._chunk_insert = record(decode, 2), record(chunk, 1)
+    res = eng.generate(_prompts(cfg, [9, 5, 7]),
+                       GenerationConfig(max_new_tokens=3))
+    seen.append([np.asarray(r.tokens) for r in res])
+
+    ps, n_pages = 4, 4
+    pt = jnp.asarray(np.arange(1, 1 + 2 * n_pages).reshape(2, n_pages))
+    toks = jnp.asarray(_prompts(cfg, [4])[0][None], jnp.int32)
+    step = jax.jit(lambda prm, c: T.chunk_step(
+        prm, toks, 0, 0, 4, c, cfg, layout=A.PagedLayout(ps),
+        page_table=pt, read_len=12, dist=eng.dist)[0])
+    logits = step(params, T.init_paged_cache(cfg, 1 + 2 * n_pages, ps, 2,
+                                             dtype=jnp.float32))
+    return seen, np.asarray(logits), jax.tree.map(np.asarray, eng._cache)
+
+
+@pytest.mark.parametrize("p", [1, 2], ids=["p1", "p2_mode_grouped"])
+def test_stacked_expert_weights_bitwise_equal_layer_slices(served, p,
+                                                           monkeypatch):
+    """The fused kernel reading (stack, layer) serves the same bits as the
+    kernel fed each layer's slice of the stacks: decode logits and greedy
+    tokens, chunk logits and first tokens, served tokens, and the final
+    KV pools and counters."""
+    cfg, params = served
+    prepared, dist = _fused_dist(cfg, params, p)
+    stacked = _serve_recorded(cfg, prepared, dist)
+    with monkeypatch.context() as m:
+        m.setattr(moe_mod, "reads_layer_stack", lambda *a, **k: False)
+        sliced = _serve_recorded(cfg, prepared, dist)
+    for got, want in zip(jax.tree.leaves(stacked), jax.tree.leaves(sliced),
+                         strict=True):
+        assert got.shape == want.shape and (got == want).all()
+
+
+_EXPERT_SLICE = re.compile(
+    r"= \w+\[(\d+),(\d+),(\d+)\]\{[^}]*\} .*dynamic.slice")
+
+
+def _expert_slices(text, w1_shape):
+    """Instructions of compiled HLO text that dynamic-slice one layer's
+    whole expert weight, (Es, d, f) or (Es, f, d), out of its stack."""
+    _, Es, d, f = w1_shape
+    whole = {(Es, d, f), (Es, f, d)}
+    return [line for line in text.splitlines()
+            if (m := _EXPERT_SLICE.search(line))
+            and tuple(map(int, m.groups())) in whole]
+
+
+@pytest.mark.parametrize("fused", [True, False], ids=["fused", "einsum"])
+def test_expert_stacks_sliced_only_off_the_fused_path(served, fused):
+    """Both compiled serving programs: with the fused kernel no
+    instruction slices a layer's expert weights out of the stacks (the
+    kernel indexes the layer itself); on the einsum path the scan's
+    per-layer slices are still there."""
+    cfg, params = served
+    prepared, dist = _fused_dist(cfg, params, 2)
+    if not fused:
+        dist = dataclasses.replace(dist, policy=dataclasses.replace(
+            dist.policy, use_kernel=False, fused_pipeline=False))
+    eng = PagedEngine(cfg, prepared, dist=dist, n_slots=2, page_size=4,
+                      chunk_size=4, max_prompt_len=12, max_new_tokens=4)
+    w1_shape = prepared["blocks"]["moe"]["w1"].shape
+    for text in (eng.decode_hlo(), eng.chunk_hlo()):
+        found = _expert_slices(text, w1_shape)
+        assert (not found) if fused else len(found) >= 3, found
 
 
 def test_paged_rejects_oversized_and_unsupported(served):

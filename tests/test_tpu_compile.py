@@ -45,32 +45,40 @@ def _sds(sharding, shape, dtype=jnp.bfloat16):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _weights(sharding, p):
+def _weights(sharding, p, n_layers=0):
     n_sub, f = N_EXPERTS * p, D_EXPERT // p
-    return (_sds(sharding, (n_sub, D_MODEL, f)),
-            _sds(sharding, (n_sub, D_MODEL, f)),
-            _sds(sharding, (n_sub, f, D_MODEL)))
+    stack = (n_layers,) if n_layers else ()
+    return (_sds(sharding, stack + (n_sub, D_MODEL, f)),
+            _sds(sharding, stack + (n_sub, D_MODEL, f)),
+            _sds(sharding, stack + (n_sub, f, D_MODEL)))
 
 
-@pytest.mark.parametrize("n_tokens,p", [(8, 1), (128, 2)],
-                         ids=["decode_p1", "prefill_chunk_p2"])
-def test_streamed_fused_pipeline_compiles(one_chip, n_tokens, p):
+@pytest.mark.parametrize(
+    "n_tokens,p,n_layers", [(8, 1, 0), (128, 2, 0), (8, 1, 4), (128, 2, 4)],
+    ids=["decode_p1", "prefill_chunk_p2", "decode_p1_stacked",
+         "prefill_chunk_p2_stacked"])
+def test_streamed_fused_pipeline_compiles(one_chip, n_tokens, p, n_layers):
     """The serving default: exact capacity (capacity == T), P=1 with the
-    minor-half split off, or mode-grouped P=2 with minor-half skipping."""
+    minor-half split off, or mode-grouped P=2 with minor-half skipping.
+    Stacked: the weights are a 4-layer stack and the kernel reads a traced
+    layer of it (layer index in SMEM, weight blocks squeeze the layer
+    axis)."""
     block_c = min(128, n_tokens)
     n_pairs = n_tokens * TOP_K + block_c
-    args = (_sds(one_chip, (n_tokens, D_MODEL)), *_weights(one_chip, p),
+    args = (_sds(one_chip, (n_tokens, D_MODEL)),
+            *_weights(one_chip, p, n_layers),
             *(_sds(one_chip, (N_EXPERTS,), jnp.int32) for _ in range(3)),
             _sds(one_chip, (n_pairs,), jnp.int32),
             _sds(one_chip, (n_pairs,), jnp.float32))
     n_minor_start = None if p > 1 else D_EXPERT
 
-    def fused(*a):
+    def fused(*a, layer=None):
         return fused_moe_pipeline_pallas(
             *a, capacity=n_tokens, p_factor=p, n_minor_start=n_minor_start,
-            interpret=False)
+            layer=layer, interpret=False)
 
-    compiled = jax.jit(fused).lower(*args).compile()
+    layer = _sds(one_chip, (), jnp.int32) if n_layers else None
+    compiled = jax.jit(fused).lower(*args, layer=layer).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
 
