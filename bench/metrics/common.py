@@ -16,7 +16,8 @@ def moe_roofline(ctx):
     ns, calls = ctx.kernel
     if not calls or not ctx.steps:
         return None
-    need = work.moe_needed(ctx.s, ctx.p, ctx.steps, work.kept_share(ctx.counts))
+    need = ctx.family.moe_needed(ctx.s, ctx.p, ctx.steps,
+                                  work.kept_share(ctx.counts))
     return work.roofline_share(need["flops"], need["bytes"], ns * 1e-9,
                                ctx.peaks)
 
@@ -27,7 +28,8 @@ def mfu(ctx):
     wall = sum(r["wall_s"] for r in ctx.steps)
     if not wall:
         return None
-    flops = work.model_flops(ctx.s, ctx.steps, work.kept_share(ctx.counts))
+    flops = ctx.family.model_flops(ctx.s, ctx.steps,
+                                   work.kept_share(ctx.counts))
     return 100.0 * flops / (wall * ctx.peaks["bf16_flops"])
 
 

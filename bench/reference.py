@@ -1,14 +1,17 @@
 """Plain float32 forward of the benchmarked MoE decoders: the yardstick.
 
-Imports nothing of the program. It follows the published layer equations
-(pre-norm decoder; grouped-query attention with rotate-half RoPE; a
-softmax router choosing the top k experts, renormalised where the
-configuration says so; SwiGLU experts) and, for 2T-Drop cells, the
-DualSparse semantics the cell states: each expert's neurons ranked by
-importance on calibration activations, the top 1/P of them its major part;
-per-layer thresholds at the drop-rate quantiles ``target -+ delta`` of the
-calibration scores; a pair above the upper threshold computes the whole
-expert, between the two the major part, below the lower one nothing.
+Imports nothing of the program. This module holds what every family
+shares; each family module (``bench/families/<model_type>.py``) builds its
+layers from it, following the published layer equations. Shared here: the
+pre-norm pieces (RMSNorm, rotate-half RoPE), a softmax router choosing the
+top k experts, renormalised where the configuration says so, the MoE block
+of SwiGLU experts with, for 2T-Drop cells, the DualSparse semantics the
+cell states (each expert's neurons ranked by importance on calibration
+activations, the top 1/P of them its major part; per-layer thresholds at
+the drop-rate quantiles ``target -+ delta`` of the calibration scores; a
+pair above the upper threshold computes the whole expert, between the two
+the major part, below the lower one nothing), the LM head and the gaps of
+served tokens.
 
 Every matrix product runs at ``Precision.HIGHEST`` in float32 from the
 bf16 weights the served program was given. It runs layer by layer over one
@@ -76,26 +79,6 @@ def route(h, wg, s, quant=False):
     return idx, (norm if s["renorm"] else vals), norm
 
 
-def _attention(q, k, v, quant):
-    """Causal attention; q (T,Hkv,G,D), k/v (T,Hkv,D), T % Q_BLOCK == 0."""
-    T, hkv, g, hd = q.shape
-    nb = T // Q_BLOCK
-    kpos = jnp.arange(T)
-
-    def block(args):
-        qb, b = args
-        s = _dot("qhgd,thd->qhgt", qb, k, quant, (3,), (2,)) / np.sqrt(hd)
-        qpos = b * Q_BLOCK + jnp.arange(Q_BLOCK)
-        mask = kpos[None, :] <= qpos[:, None]
-        s = jnp.where(mask[:, None, None, :], s, -1e30)
-        p = jax.nn.softmax(s, axis=-1)
-        return _dot("qhgt,thd->qhgd", p, v, quant, (3,), (0,))
-
-    out = jax.lax.map(block, (q.reshape(nb, Q_BLOCK, hkv, g, hd),
-                              jnp.arange(nb)))
-    return out.reshape(T, hkv, g, hd)
-
-
 def moe_layer(h, moe, l, thr, major, *, s, two_t, quant=False):
     """Layer ``l`` of the MoE block over tokens h (T, d): top-k routing,
     2T modes when ``two_t``, and every expert's SwiGLU over the tokens
@@ -124,34 +107,6 @@ def moe_layer(h, moe, l, thr, major, *, s, two_t, quant=False):
     y, _ = jax.lax.scan(expert, jnp.zeros(h.shape, jnp.float32),
                         jnp.arange(s["experts"]))
     return y
-
-
-def _layer(blocks, l, x, thr, major, *, s, two_t, quant, capture=False):
-    """One decoder layer over a whole (padded) sequence x (T, d)."""
-    at, moe = blocks["attn"], blocks["moe"]
-    T = x.shape[0]
-    pos = jnp.arange(T)
-    h = rms_norm(x, blocks["ln1"][l], s["eps"])
-    q = rope(_dot("td,dhgk->thgk", h, at["wq"][l], quant, (1,), (0,)),
-             pos, s["theta"])
-    k = rope(_dot("td,dhk->thk", h, at["wk"][l], quant, (1,), (0,)),
-             pos, s["theta"])
-    v = _dot("td,dhk->thk", h, at["wv"][l], quant, (1,), (0,))
-    o = _attention(q, k, v, quant)
-    x = x + _dot("thgk,hgkd->td", o, at["wo"][l], quant, (1, 2, 3),
-                 (0, 1, 2))
-    h = rms_norm(x, blocks["ln2"][l], s["eps"])
-    y = moe_layer(h, moe, l, thr, major, s=s, two_t=two_t, quant=quant)
-    if capture:
-        return x + y, h
-    return x + y
-
-
-@functools.lru_cache(maxsize=None)
-def _layer_fn(frozen, two_t, quant, capture):
-    s = dict(frozen)
-    return jax.jit(functools.partial(_layer, s=s, two_t=two_t, quant=quant,
-                                     capture=capture))
 
 
 def _head(final_norm, lm_head, x, target, other, *, s, quant):
@@ -202,47 +157,21 @@ def _pad(tokens: np.ndarray) -> np.ndarray:
 
 
 class Reference:
-    """The reference model over one set of weights.
+    """The reference model over one set of weights; a family's subclass
+    (``bench/families``) gives its layers as ``_run(tokens, quant)``: the
+    final hidden states (T, d) float32 of a padded token sequence.
 
     ``params``: the weight tree (bf16) as made by ``bench.weights``;
-    ``s``: sizes from ``bench.weights.sizes``; ``two_t``: None for plain
-    top-k, else a dict with per-layer ``thresholds`` (L, 2) and ``major``
-    (L, E, f) bool from :meth:`calibrate_2t`."""
+    ``s``: the family's sizes; ``two_t``: None for plain top-k, else the
+    family's ``calibrate_2t`` result."""
 
     def __init__(self, params, s: Dict, two_t: Optional[Dict] = None):
         self.params = params
         self.s = s
         self.two_t = two_t
 
-    def _run(self, tokens, quant, capture=False):
-        s = self.s
-        blocks = self.params["blocks"]
-        x = self.params["embed"]["embedding"][jnp.asarray(tokens)].astype(
-            jnp.float32)
-        L, E, f = s["layers"], s["experts"], s["f"]
-        if self.two_t is not None:
-            thr, major = self.two_t["thresholds"], self.two_t["major"]
-        else:
-            thr = jnp.zeros((L, 2), jnp.float32)
-            major = jnp.zeros((L, E, f), bool)
-        fn = _layer_fn(_frozen(s), self.two_t is not None and not capture,
-                       quant, capture)
-        hs = []
-        for layer in range(L):
-            out = fn(blocks, jnp.int32(layer), x, thr, major)
-            if capture:
-                x, h = out
-                hs.append(h)
-            else:
-                x = out
-        return (x, hs) if capture else x
-
-    def moe_inputs(self, tokens: np.ndarray):
-        """Per layer, the (unpadded) hidden states entering the MoE router,
-        with every pair computed whole: calibration activations."""
-        n = len(tokens)
-        _, hs = self._run(_pad(tokens), quant=False, capture=True)
-        return [h[:n] for h in hs]
+    def _run(self, tokens, quant):
+        raise NotImplementedError
 
     def rows(self, tokens: np.ndarray, target: np.ndarray,
              other: Optional[np.ndarray] = None, *, quant: bool = False):
@@ -308,28 +237,3 @@ def quantile_index(frac: float, n: int) -> int:
     """Index into n sorted scores of the threshold at drop rate ``frac``,
     the product taken in float32."""
     return int(min(max(np.floor(np.float32(frac) * np.float32(n)), 0), n - 1))
-
-
-def calibrate_2t(params, s: Dict, calib, *, p: int, importance: str,
-                 drop_target: float, delta: float) -> Dict:
-    """Per-layer thresholds (L, 2) and major-neuron masks (L, E, f) from
-    calibration activations ``calib`` (list of (T, d) per layer)."""
-    moe = params["blocks"]["moe"]
-    thr, major = [], []
-    f = s["f"]
-    for layer, h in enumerate(calib):
-        h = jnp.asarray(h, jnp.float32)
-        idx, _, norm = route(h, moe["wg"][layer], s)
-        flat = np.sort(np.asarray(norm).reshape(-1))
-        n = flat.size
-        tm = flat[quantile_index(max(drop_target - delta, 0.0), n)]
-        tn = flat[quantile_index(min(drop_target + delta, 1.0), n)]
-        thr.append([tm, tn])
-        imp = _importance(h, moe["w1"][layer], moe["w3"][layer], idx,
-                          importance)
-        order = np.argsort(-np.asarray(imp), axis=-1, kind="stable")
-        mask = np.zeros((s["experts"], f), bool)
-        np.put_along_axis(mask, order[:, :f // p], True, axis=-1)
-        major.append(mask)
-    return {"thresholds": jnp.asarray(np.array(thr, np.float32)),
-            "major": jnp.asarray(np.stack(major))}

@@ -7,7 +7,9 @@ A cell (``workloads`` in ``BENCHMARK.json``) is a model configuration
 (``bench/configs/<config>.json``) under a traffic mix
 (``bench/traffic/<traffic>.json``), with its own fixed numbers in
 ``bench/cells/<cell>.json`` (the open-loop rate, the limits of the
-correctness check).
+correctness check). The configuration's ``model_type`` names its family
+module (``bench/families/<model_type>.py``): its sizes, weight tree,
+program configuration, reference layers and work counts.
 
 Set-up (timed as ``setup_s`` from process start): seeded bf16 weights made
 on the chip; for a 2T-Drop mix, calibration activations from the
@@ -18,11 +20,12 @@ of traffic through ``submit``/``step``. ``--trace 0`` prints the cell's
 end-to-end metrics; ``--trace 1`` profiles a few seconds in the middle of
 the window and prints its per-layer metrics. After the window the
 program's state is freed, the weights are drawn again, and the float32
-reference (``bench/reference.py``) is run over a seeded sample of the
-finished requests: every served token's logit must lie within the cell's
-limit of the reference's best. The last line of standard output is one
-JSON object; the numbers compared, each beside its limit, are the last
-lines of standard error and the result's last key.
+reference (the family's ``Reference`` on ``bench/reference.py``) is run
+over a seeded sample of the finished requests: every served token's logit
+must lie within the cell's limit of the reference's best. The last line
+of standard output is one JSON object; the numbers compared, each beside
+its limit, are the last lines of standard error and the result's last
+key.
 
 Exits non-zero, printing no result, where JAX finds no TPU or fewer chips
 than the cell asks for.
@@ -77,6 +80,7 @@ class Cell:
     fixed: Dict                    # bench/cells/<cell>.json
     end_to_end: List[Dict]
     per_layer: List[Dict]
+    family: object                 # bench/families/<model_type>.py
 
 
 def load_cell(name: str, root: str = ROOT) -> Cell:
@@ -92,14 +96,32 @@ def load_cell(name: str, root: str = ROOT) -> Cell:
     per = [m for m in spec["per_layer"]
            if (name in m["workloads"] if "workloads" in m
                else m["moves"] in moved)]
+    config = _json(os.path.join(root, conf["file"]))
     return Cell(name=name, chips=w["chips"], config_name=w["config"],
-                config=_json(os.path.join(root, conf["file"])),
-                traffic_name=w["traffic"],
+                config=config, traffic_name=w["traffic"],
                 mix=_json(os.path.join(root, "bench", "traffic",
                                        w["traffic"] + ".json")),
                 fixed=_json(os.path.join(root, "bench", "cells",
                                          name + ".json")),
-                end_to_end=e2e, per_layer=per)
+                end_to_end=e2e, per_layer=per, family=family(config, root))
+
+
+def family(config: Dict, root: str = ROOT):
+    """The family module of a configuration, found by its ``model_type``:
+    ``bench/families/<model_type>.py`` under ``root``."""
+    kind = config.get("model_type")
+    path = os.path.join(root, "bench", "families", f"{kind}.py")
+    if not os.path.isfile(path):
+        raise SystemExit(f"no benchmark family for model_type {kind!r}: "
+                         f"looked for {path}")
+    name = f"bench.families.{kind}"
+    mod = sys.modules.get(name)
+    if mod is None or os.path.abspath(mod.__file__) != os.path.abspath(path):
+        spec = importlib.util.spec_from_file_location(name, path)
+        mod = importlib.util.module_from_spec(spec)
+        sys.modules[name] = mod
+        spec.loader.exec_module(mod)
+    return mod
 
 
 def reader(metric: str):
@@ -173,6 +195,7 @@ class Compiles:
 @dataclasses.dataclass
 class Context:
     """What the per-layer readers read (see ``bench/metrics``)."""
+    family: object               # the configuration's family module
     s: Dict                      # model sizes
     p: int                       # sub-experts per expert in the served weights
     peaks: Dict
@@ -268,7 +291,7 @@ class Tracer:
         return {"wall_s": step.t1 - step.t0, "decode_keys": keys,
                 "chunk": chunk}
 
-    def finish(self, s: Dict, sizes_p: int, peaks: Dict) -> tuple:
+    def finish(self, fam, s: Dict, sizes_p: int, peaks: Dict) -> tuple:
         """(Context, breakdown, busy_s, window_s) from the trace."""
         from bench import xplane
         loads = [np.asarray(m.expert_load) for m in self.snaps]
@@ -283,8 +306,9 @@ class Tracer:
         win = xplane.loop_window(tr)
         window_s = (win[1] - win[0]) * 1e-9
         busy_s = xplane.busy_ns(tr, win) * 1e-9
-        ctx = Context(s=s, p=sizes_p, peaks=peaks, steps=self.steps,
-                      counts=counts, programs=xplane.programs(tr, win),
+        ctx = Context(family=fam, s=s, p=sizes_p, peaks=peaks,
+                      steps=self.steps, counts=counts,
+                      programs=xplane.programs(tr, win),
                       kernel=xplane.kernel_ns(tr, win), busy_s=busy_s,
                       window_s=window_s)
         idle = sorted(xplane.idle_by_span(tr, win).items(),
@@ -347,11 +371,14 @@ def gap_stats(gaps: np.ndarray) -> Dict[str, float]:
 
 
 def judge(stats: Dict[str, float], limits: Dict, overflow: int,
-          bad_ids: int) -> Dict[str, Dict]:
-    """The numbers compared, each with its limit."""
+          bad_ids: int, dropped: Optional[int] = None) -> Dict[str, Dict]:
+    """The numbers compared, each with its limit. ``dropped``, the pairs
+    the program dropped, is given where the cell's policy drops none."""
     checks = {name: {"value": stats[name], "limit": limit}
               for name, limit in limits.items()}
     checks["overflow_pairs"] = {"value": overflow, "limit": 0}
+    if dropped is not None:
+        checks["dropped_pairs"] = {"value": dropped, "limit": 0}
     checks["token_ids_outside_vocab"] = {"value": bad_ids, "limit": 0}
     return checks
 
@@ -361,7 +388,7 @@ def passes(checks: Dict[str, Dict]) -> bool:
 
 
 def check(sample, tokens_of, ref, limits: Dict, overflow: int,
-          vocab: int, control=None) -> tuple:
+          vocab: int, control=None, dropped: Optional[int] = None) -> tuple:
     """The numbers compared, each with its limit; the served tokens' gap
     statistics and how many tokens they cover; and, given the ``control``
     reference, the same statistics of the tokens the control would have
@@ -382,8 +409,8 @@ def check(sample, tokens_of, ref, limits: Dict, overflow: int,
     ctrl_stats = None
     if control is not None:
         ctrl_stats = gap_stats(np.concatenate(ctrl) if ctrl else np.zeros(0))
-    return (judge(stats, limits, overflow, bad_ids), stats, len(gaps),
-            ctrl_stats)
+    return (judge(stats, limits, overflow, bad_ids, dropped), stats,
+            len(gaps), ctrl_stats)
 
 
 # ---------------------------------------------------------------------------
@@ -409,23 +436,19 @@ def build(cell: Cell, seed: int) -> Built:
     """Weights, policy and a warmed-up engine for ``cell`` at ``seed``."""
     import jax
     import jax.numpy as jnp
-    from bench import reference, system, weights
+    from bench import system, weights
 
-    s = weights.sizes(cell.config)
-    mc = system.model_config(cell.config_name, cell.config, s)
+    fam = cell.family
+    s = fam.sizes(cell.config)
+    mc = fam.model_config(cell.config_name, cell.config, s)
     mix = cell.mix
-    params = jax.block_until_ready(weights.make(s, seed))
+    params = jax.block_until_ready(weights.make(fam.layout(s), seed))
     system.check_tree(mc, params)
     policy = system.policy_of(mc, mix)
     calib = None
     if policy is not None:
-        ref = reference.Reference(params, s)
-        per_prompt = [ref.moe_inputs(t)
-                      for t in calib_tokens(mix, seed, s["vocab"])]
-        calib = np.stack([np.concatenate([np.asarray(h[layer])
-                                          for h in per_prompt])
-                          for layer in range(s["layers"])])
-        del ref, per_prompt
+        calib = fam.Reference(params, s).calibration(
+            calib_tokens(mix, seed, s["vocab"]))
     params, dist = system.apply_policy(mc, params, policy,
                                        None if calib is None
                                        else jnp.asarray(calib))
@@ -449,13 +472,14 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, devices, *,
     the programs set-up and the window took from the compile cache or
     compiled."""
     import jax
-    from bench import reference, system, weights
+    from bench import system, weights
     from bench.peaks import peaks as peaks_of
     from bench.traffic.generator import generate
     from bench.traffic.loops import Loop
 
     dev = devices[0]
     mix = cell.mix
+    fam = cell.family
     b = build(cell, seed)
     eng, s, policy, calib = b.eng, b.s, b.policy, b.calib
     del b
@@ -489,6 +513,10 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, devices, *,
     mem = dev.memory_stats() or {}
     peak = int(mem.get("peak_bytes_in_use", 0))
     overflow = int(eng.overflow_pairs)
+    # every pair computed whole where the policy drops none (the program's
+    # counter, since set-up)
+    dropped = (None if policy is not None
+               else int(eng._device_metrics().dropped_pairs))
     late = max(win.late_s) if win.late_s else 0.0
     log(f"bench: window {seconds:.0f} s: {len(win.served)} requests sent, "
         f"{len(win.steps)} steps; generator at most {1e3 * late:.2f} ms "
@@ -503,7 +531,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, devices, *,
         if tracer.state != "done":
             raise RuntimeError("the traced part of the window never closed")
         ctx, breakdown, busy_s, window_s = tracer.finish(
-            s, 1 if policy is None else policy.partition_p,
+            fam, s, 1 if policy is None else policy.partition_p,
             peaks_of(dev.device_kind))
         tmp.cleanup()
         metrics = {}
@@ -527,20 +555,21 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, devices, *,
     del eng, loop, tracer
     gc.collect()
 
-    params = jax.block_until_ready(weights.make(s, seed))
+    params = jax.block_until_ready(weights.make(fam.layout(s), seed))
     two_t = None
     if policy is not None:
-        two_t = reference.calibrate_2t(
+        two_t = fam.calibrate_2t(
             params, s, calib, p=policy.partition_p,
             importance=cell.config["dualsparse"]["importance"],
             drop_target=mix["policy"]["drop_target"],
             delta=mix["policy"]["delta"])
-    ref = reference.Reference(params, s, two_t)
+    ref = fam.Reference(params, s, two_t)
     sample = sample_finished(win, mix["check"]["sample_requests"], seed)
     t_ref = time.perf_counter()
     checks, stats, n_tok, ctrl = check(
         sample, lambda x: results_of[x.uid], ref, cell.fixed["limits"],
-        overflow, s["vocab"], control=ref if control else None)
+        overflow, s["vocab"], control=ref if control else None,
+        dropped=dropped)
     log(f"bench: reference over {len(sample)} requests ({n_tok} served "
         f"tokens) in {time.perf_counter() - t_ref:.1f} s; gaps: " + ", ".join(
             f"{k} {v}" for k, v in stats.items()), file=sys.stderr)

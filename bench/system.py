@@ -1,12 +1,13 @@
 """The system under test: the served program, built for one cell.
 
-This is the only module of the benchmark that imports the program
-(``src/repro``). It turns a configuration file into the program's
-``ModelConfig``, checks that the benchmark's weights have the tree the
-program takes, applies the cell's sparsity policy (for 2T-Drop: partition,
-reconstruction and per-layer thresholds, calibrated by the program on the
-calibration activations the benchmark hands it), and builds the
-``PagedEngine`` whose ``submit``/``step`` the timed window drives.
+This module and the family modules (``bench/families``), which turn a
+configuration file into the program's ``ModelConfig``, are the only ones
+of the benchmark that import the program (``src/repro``). This one checks
+that the benchmark's weights have the tree the program takes, applies the
+cell's sparsity policy (for 2T-Drop: partition, reconstruction and
+per-layer thresholds, calibrated by the program on the calibration
+activations the benchmark hands it), and builds the ``PagedEngine`` whose
+``submit``/``step`` the timed window drives.
 """
 from __future__ import annotations
 
@@ -21,29 +22,12 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if os.path.join(ROOT, "src") not in sys.path:
     sys.path.insert(0, os.path.join(ROOT, "src"))
 
-from repro.configs.base import DualSparseConfig, ModelConfig  # noqa: E402
+from repro.configs.base import ModelConfig  # noqa: E402
 from repro.core.policy import make_policy  # noqa: E402
 from repro.launch.mesh import make_host_mesh  # noqa: E402
 from repro.models import model as M  # noqa: E402
 from repro.models.transformer import DistContext  # noqa: E402
 from repro.serving import GenerationConfig, PagedEngine  # noqa: E402
-
-
-def model_config(name: str, cfg: Dict, s: Dict) -> ModelConfig:
-    ds = cfg["dualsparse"]
-    return ModelConfig(
-        arch_id=name, family="moe", source=cfg["source"],
-        n_layers=s["layers"], d_model=s["d"], n_heads=s["hq"],
-        n_kv_heads=s["hkv"], head_dim=s["hd"], d_ff=s["f"],
-        vocab_size=s["vocab"], attn_kind="gqa", rope_theta=s["theta"],
-        n_experts=s["experts"], top_k=s["top_k"], d_expert=s["f"],
-        router_norm_topk=s["renorm"], norm_eps=s["eps"],
-        tie_embeddings=False,
-        dualsparse=DualSparseConfig(
-            enabled=True, partition_p=ds["partition_p"],
-            t_drop=ds["t_drop"], t_major=ds["t_major"],
-            t_minor=ds["t_minor"], importance=ds["importance"],
-            t_max=ds["t_max"]))
 
 
 def check_tree(mc: ModelConfig, params) -> None:

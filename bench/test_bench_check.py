@@ -41,8 +41,17 @@ def _plant(eng, fault: str) -> None:
         raise ValueError(f"unknown fault {fault!r}")
 
 
-@pytest.mark.parametrize("fault", ["token", "state"])
-def test_broken_served_path_is_not_correct(fault, monkeypatch):
+# the fault, and the gap statistic the cell's limit names; the cases of
+# the widest gap keep the fault's name as their id
+BROKEN = [pytest.param(f, stat, id=f if stat == "widest_gap" else
+                       f"{f}-{stat}")
+          for stat in ("widest_gap", "mean_gap") for f in ("token", "state")]
+
+
+@pytest.mark.parametrize("fault,stat", BROKEN)
+def test_broken_served_path_is_not_correct(fault, stat, monkeypatch):
+    """A mean is at most the widest gap, so sound runs pass the tiny limit
+    under either statistic."""
     build = system.engine
 
     def broken(*a, **k):
@@ -50,10 +59,34 @@ def test_broken_served_path_is_not_correct(fault, monkeypatch):
         _plant(eng, fault)
         return eng
     monkeypatch.setattr(system, "engine", broken)
-    res = R.run(tiny_cell("none", "open", TINY_LIMIT), 31, 3.0, False,
-                jax.devices(), log=lambda *a, **k: None)
+    cell = tiny_cell("none", "open", TINY_LIMIT)
+    cell.fixed = {**cell.fixed, "limits": {stat: TINY_LIMIT}}
+    res = R.run(cell, 31, 3.0, False, jax.devices(),
+                log=lambda *a, **k: None)
     assert not res["correct"], res["check"]
-    assert res["check"]["widest_gap"]["value"] > TINY_LIMIT
+    assert res["check"][stat]["value"] > TINY_LIMIT
+
+
+def test_dropping_under_a_no_drop_cell_is_not_correct(monkeypatch):
+    """The program prepared for 2T-Drop where the cell's policy drops
+    none: the dropped pairs, held at 0, fail the run whatever the gaps
+    read."""
+    prepare = system.apply_policy
+    cell = tiny_cell("none", "open", TINY_LIMIT)
+    two_t = tiny_cell("2t").mix
+
+    def dropping(mc, params, policy, calib):
+        fam = cell.family
+        s = fam.sizes(cell.config)
+        calib = fam.Reference(params, s).calibration(
+            R.calib_tokens(two_t, 31, s["vocab"]))
+        return prepare(mc, params, system.policy_of(mc, two_t),
+                       jnp.asarray(calib))
+    monkeypatch.setattr(system, "apply_policy", dropping)
+    res = R.run(cell, 31, 3.0, False, jax.devices(),
+                log=lambda *a, **k: None)
+    assert not res["correct"], res["check"]
+    assert res["check"]["dropped_pairs"]["value"] > 0
 
 
 def test_control_reads_above_the_limit():

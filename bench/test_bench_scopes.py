@@ -13,7 +13,6 @@ import pytest
 
 from bench import run as R
 from bench import scopes, xplane
-from bench.weights import sizes
 
 FIXTURES = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                         "fixtures")
@@ -86,7 +85,8 @@ def test_device_by_scope_maps_ops_through_their_program():
 
 
 def _ctx(**extra):
-    ctx = R.Context(s=sizes(R.load_cell("qwen3-30b-a3b.chat-2t").config),
+    cell = R.load_cell("qwen3-30b-a3b.chat-2t")
+    ctx = R.Context(family=cell.family, s=cell.family.sizes(cell.config),
                     p=1, peaks={"bf16_flops": 1.0, "hbm_bytes_per_s": 1.0},
                     steps=[{}] * 4, counts=[0, 0, 0], programs={},
                     kernel=(0.0, 0), busy_s=0.0, window_s=0.0)

@@ -2,6 +2,7 @@
 short open-loop mix, and a run of the whole harness on it."""
 from __future__ import annotations
 
+import hashlib
 import os
 import sys
 
@@ -12,7 +13,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
 TINY = {
-    "source": "toy widths for tests", "hidden_size": 64,
+    "source": "toy widths for tests", "model_type": "qwen3_moe",
+    "hidden_size": 64,
     "num_hidden_layers": 2, "num_attention_heads": 4,
     "num_key_value_heads": 2, "head_dim": 16, "moe_intermediate_size": 32,
     "num_experts": 4, "num_experts_per_tok": 2, "norm_topk_prob": True,
@@ -25,7 +27,8 @@ TINY = {
 # the Mixtral layout at toy widths: 8 coarse experts, top-2, 4 query heads
 # per KV head
 TINY_COARSE = {
-    "source": "toy widths for tests", "hidden_size": 128,
+    "source": "toy widths for tests", "model_type": "mixtral",
+    "hidden_size": 128,
     "num_hidden_layers": 2, "num_attention_heads": 8,
     "num_key_value_heads": 2, "intermediate_size": 96,
     "num_local_experts": 8, "num_experts_per_tok": 2, "vocab_size": 128,
@@ -57,7 +60,7 @@ def tiny_cell(policy: str = "none", loop: str = "open",
                   traffic_name="tiny", mix=mix,
                   fixed={"rate_per_s": 6.0,
                          "limits": {"widest_gap": widest_gap}},
-                  end_to_end=e2e, per_layer=[])
+                  end_to_end=e2e, per_layer=[], family=R.family(TINY))
 
 
 @pytest.mark.parametrize("policy,loop", [("none", "closed"), ("2t", "open")])
@@ -66,6 +69,9 @@ def test_tiny_run_is_correct(policy, loop):
     res = R.run(tiny_cell(policy, loop, widest_gap=TINY_LIMIT), 2**33 + 7,
                 3.0, False, jax.devices(), log=lambda *a, **k: None)
     assert res["correct"], res["check"]
+    # a cell whose policy drops none holds the dropped pairs at 0
+    assert ("dropped_pairs" in res["check"]) == (policy == "none")
+    assert res["check"].get("dropped_pairs", {"value": 0})["value"] == 0
     assert res["attempted"] > 0 and res["failed"] == 0
     assert set(res["metrics"]) == {"ttft_p90_ms", "itl_p95_ms",
                                    "offline_tok_s", "setup_s"}
@@ -76,3 +82,32 @@ def test_tiny_run_is_correct(policy, loop):
 # sound runs read at most 1.8e-3 (bf16 program vs float32 reference), the
 # float8 control at least 6.3e-3, the planted faults 0.42 and more.
 TINY_LIMIT = 4.5e-3
+
+
+# sha256 of every leaf's bf16 bits, in the tree's order, as the weights
+# were drawn before the layouts moved into bench/families: the same tree,
+# the same fold_in index per leaf, the same bits
+WEIGHT_DIGESTS = {
+    ("TINY", 5):
+        "2ac441e09fcb84ec2a58a4a94ad0cee385d95f40df2bb37dc891e9fb198e9bc9",
+    ("TINY", 2**33 + 5):
+        "af1bcd53e814cbb9eb35fcc10d8f2f8f29ab3e7fb8542ab0f48ab28ab14f486c",
+    ("TINY_COARSE", 5):
+        "138ec2b502284d581a71c3d05336f57b0627f3b6092c59e92628bf62cc7891e0",
+    ("TINY_COARSE", 2**33 + 5):
+        "1a5b5e5f2ba515a2e20dd55f275f73b46a0501713439558ca8e826b1c270b0f5",
+}
+
+
+@pytest.mark.parametrize("name,seed", sorted(WEIGHT_DIGESTS))
+def test_weights_keep_their_bits(name, seed):
+    import numpy as np
+    from bench import run as R
+    from bench import weights
+    cfg = {"TINY": TINY, "TINY_COARSE": TINY_COARSE}[name]
+    fam = R.family(cfg)
+    params = weights.make(fam.layout(fam.sizes(cfg)), seed)
+    h = hashlib.sha256()
+    for leaf in jax.tree.leaves(params):
+        h.update(np.asarray(leaf).view(np.uint16).tobytes())
+    assert h.hexdigest() == WEIGHT_DIGESTS[name, seed]
